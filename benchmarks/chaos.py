@@ -53,7 +53,7 @@ from repro.serving.router import ReplicaRouter
 from repro.serving.transport import LoopbackTransport
 
 from .common import BenchConfig, FAST_PATH_ROWS, dataset, emit, persist, \
-    workload
+    use_compile_cache, workload
 from .throughput import BUDGETS_PATH, SHARD_WINDOW, check_budgets
 
 REPLICAS = 4
@@ -276,6 +276,7 @@ def main(argv=None) -> int:
                         help="small workload + budget gate (CI job)")
     parser.add_argument("--clients", type=int, default=16)
     args = parser.parse_args(argv)
+    use_compile_cache()
     cfg = BenchConfig.default()
     assert cfg is not None  # env-validated scales
     out = run_sweep(smoke=args.smoke, clients=args.clients)

@@ -65,6 +65,21 @@ def workload(seed: int = 1):
 FAST_PATH_ROWS = 256
 
 
+def use_compile_cache() -> None:
+    """Keep JAX's persistent compilation cache at one fixed path.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    nothing is set here. Otherwise the cache lives at ``<repo>/.jax_cache``
+    (listed in ``.gitignore``): the directory is part of the cache key, so
+    a path that moved from run to run would never hit.
+    """
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return
+    import jax
+    jax.config.update("jax_compilation_cache_dir",
+                      os.path.join(REPO_ROOT, ".jax_cache"))
+
+
 def make_server(page_size: int = 100, max_mpr: int = 30,
                 cache: Optional[LRUCache] = None,
                 selector_backend: str = "numpy",

@@ -179,4 +179,7 @@ def run_selector_backends(full: bool = False) -> Dict:
 
 if __name__ == "__main__":
     import sys
+
+    from .common import use_compile_cache
+    use_compile_cache()
     run(full="--full" in sys.argv)

@@ -38,7 +38,7 @@ from repro.serving.http import app_from_config
 from repro.serving.transport import AsgiTransport, LoopbackTransport
 
 from .common import BenchConfig, FAST_PATH_ROWS, dataset, emit, persist, \
-    workload
+    use_compile_cache, workload
 from .throughput import BUDGETS_PATH, SHARD_WINDOW, check_budgets
 
 CLIENT_COUNTS = [1, 4, 16, 64]
@@ -196,6 +196,7 @@ def main(argv=None) -> int:
     parser.add_argument("--replicas", type=int, default=1,
                         help="server replicas behind the ASGI router")
     args = parser.parse_args(argv)
+    use_compile_cache()
     if args.smoke:
         out = run_sweep(kinds=("loopback",), smoke=True)
         # budget gate reads the c=8 smoke level under the plain name

@@ -44,6 +44,8 @@ def main() -> None:
     if "--only" in sys.argv:
         only = sys.argv[sys.argv.index("--only") + 1]
 
+    from .common import use_compile_cache
+    use_compile_cache()
     print("name,us_per_call,derived")
     benches = []
     from . import (network_load, pagesize, throughput, cache_hits,

@@ -20,22 +20,26 @@ synthetic hot-band dataset:
    numpy oracle, the kernel backend and the repartitioned sharded
    backend, and all three must return identical pages.
 
-The final stdout line is one JSON object (:func:`repro.core.metrics.
-rebalance_report` plus run metadata) -- ``benchmarks.throughput``
-spawns this module as a subprocess (the forced 4-device host platform
-must be configured before jax initializes) and gates
-``skew_c16:imbalance_uniform`` / ``imbalance_heat`` /
-``imbalance_drop`` from that row.
+Run it as its own command, ``python -m benchmarks.skew``: it gates
+``skew_c16:imbalance_uniform`` / ``imbalance_heat`` / ``imbalance_drop``
+(``budgets.json``) on the row it computes and persists that row to
+``BENCH_throughput.json``. The last stdout line is the row as one JSON
+object (:func:`repro.core.metrics.rebalance_report` plus run metadata).
+The mesh is every device JAX finds: the chips of a TPU host, or, with
+``JAX_PLATFORMS=cpu``, four virtual CPU devices (the A/B is meaningless
+on a one-device mesh).
 """
 from __future__ import annotations
 
 import os
 
-# Must run before jax initializes (transitively, via repro.core): the
-# placement A/B is meaningless on a 1-device mesh, and the host-platform
-# device count is fixed at backend init. An externally-set count wins.
-if "xla_force_host_platform_device_count" not in os.environ.get(
-        "XLA_FLAGS", ""):
+# The host-platform device count is fixed when jax initializes, so this
+# runs before the first jax import (transitively, via repro.core). It
+# touches only the CPU platform; an externally set count wins.
+if (__name__ == "__main__"
+        and os.environ.get("JAX_PLATFORMS") == "cpu"
+        and "xla_force_host_platform_device_count"
+        not in os.environ.get("XLA_FLAGS", "")):
     os.environ["XLA_FLAGS"] = (
         "--xla_force_host_platform_device_count=4 "
         + os.environ.get("XLA_FLAGS", ""))
@@ -180,16 +184,24 @@ def run(seed: int = 0) -> Dict:
 
 def main(argv=None) -> int:
     import argparse
+
+    from .common import persist, use_compile_cache
+    from .throughput import check_budgets
     parser = argparse.ArgumentParser(
         description="placement A/B under Zipf-skewed load")
     parser.add_argument("--seed", type=int, default=0)
     args = parser.parse_args(argv)
+    use_compile_cache()
     row = run(seed=args.seed)
     for k, v in row.items():
         if not isinstance(v, list):
             print(f"# skew/{k} = {v}", file=sys.stderr)
-    print(json.dumps(row))                    # parsed by run_skew()
-    return 0 if row["parity_ok"] else 1
+    failures = check_budgets({("skew", 16): row})
+    persist("throughput", {("skew", 16): row}, section="skew", headline={
+        f"skew_c16_{k}": row[k]
+        for k in ("imbalance_uniform", "imbalance_heat", "imbalance_drop")})
+    print(json.dumps(row))
+    return 0 if row["parity_ok"] and not failures else 1
 
 
 if __name__ == "__main__":

@@ -34,8 +34,6 @@ import asyncio
 import dataclasses
 import json
 import os
-import subprocess
-import sys
 import time
 from typing import Dict
 
@@ -45,7 +43,7 @@ from repro.core.sim import (calibrate, collect_traces, simulate,
                             split_workload)
 
 from .common import (BenchConfig, emit, make_server, persist,
-                     run_sequence, workload)
+                     run_sequence, use_compile_cache, workload)
 
 BUDGETS_PATH = os.path.join(os.path.dirname(__file__), "budgets.json")
 
@@ -341,42 +339,6 @@ def run_sharded_axis(full: bool = False) -> Dict:
 
 
 # ---------------------------------------------------------------------------
-# Workload-skew placement axis (docs/federation.md, "Placement")
-# ---------------------------------------------------------------------------
-
-
-def run_skew(timeout_s: float = 600.0) -> Dict:
-    """Heat-based placement A/B under Zipf-skewed load.
-
-    Runs ``benchmarks.skew`` in a subprocess: the A/B needs a real
-    multi-shard mesh, and the forced host-platform device count must be
-    set before jax initializes -- which, in this process, it already
-    has. The module's last stdout line is one JSON row
-    (:func:`repro.core.metrics.rebalance_report` + metadata), returned
-    keyed as ``("skew", 16)`` so ``check_budgets`` resolves the
-    ``skew_c16:*`` gates against it.
-    """
-    proc = subprocess.run(
-        [sys.executable, "-m", "benchmarks.skew"],
-        capture_output=True, text=True, timeout=timeout_s,
-        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-    if proc.returncode != 0:
-        raise RuntimeError(
-            f"benchmarks.skew failed (rc={proc.returncode}):\n"
-            f"{proc.stdout}\n{proc.stderr}")
-    row = json.loads(proc.stdout.strip().splitlines()[-1])
-    emit(
-        "throughput/skew_c16", 0.0,
-        f"shards={row['shards']};"
-        f"imbalance_uniform={row['imbalance_uniform']:.2f};"
-        f"imbalance_heat={row['imbalance_heat']:.2f};"
-        f"imbalance_drop={row['imbalance_drop']:.2f}x;"
-        f"replica_ranges={row['replica_ranges']};"
-        f"parity_ok={row['parity_ok']}")
-    return {("skew", 16): row}
-
-
-# ---------------------------------------------------------------------------
 # Unified-fragment-store axes: warm-cache skips + section-7.1 capacity sweep
 # ---------------------------------------------------------------------------
 
@@ -540,13 +502,6 @@ def headline_metrics(out: Dict) -> Dict:
     warm = out.get("warm_cache")
     if warm:
         h["warm_cache_hit_rate"] = warm["hit_rate"]
-    skew = out.get("skew", {}).get(("skew", 16))
-    if skew:
-        h.update({
-            "skew_c16_imbalance_uniform": skew["imbalance_uniform"],
-            "skew_c16_imbalance_heat": skew["imbalance_heat"],
-            "skew_c16_imbalance_drop": skew["imbalance_drop"],
-        })
     return h
 
 
@@ -559,10 +514,10 @@ def main(argv=None) -> int:
     parser.add_argument("--async-only", action="store_true",
                         help="skip the trace-replay simulation section")
     args = parser.parse_args(argv)
+    use_compile_cache()
     if args.smoke:
         results = run_async(smoke=True)
         results.update(run_hetero_mix(smoke=True))
-        results.update(run_skew())
         results["warm_cache"] = run_warm_cache(smoke=True)
         failures = check_budgets(results)
         # The smoke run is what CI executes per PR, so it must land the
@@ -573,7 +528,6 @@ def main(argv=None) -> int:
         headline = {f"smoke_{k}": v for k, v in
                     headline_metrics({"async": results,
                                       "hetero": results,
-                                      "skew": results,
                                       "warm_cache":
                                           results["warm_cache"]}).items()}
         headline["smoke_budget_failures"] = failures
@@ -587,7 +541,6 @@ def main(argv=None) -> int:
     out["async"] = run_async(full=args.full)
     out["hetero"] = run_hetero_mix(full=args.full)
     out["sharded_axis"] = run_sharded_axis(full=args.full)
-    out["skew"] = run_skew()
     out["warm_cache"] = run_warm_cache()
     out["cache_axis"] = run_cache_axis(full=args.full)
     path = persist("throughput", out, headline=headline_metrics(out))

@@ -21,6 +21,9 @@ from . import Rule
 
 REQUIRED_KWARGS = ("grid", "in_specs", "out_specs", "out_shape",
                    "interpret")
+# A ``grid_spec=`` (e.g. ``pltpu.PrefetchScalarGridSpec`` for scalar
+# prefetch) carries these three itself.
+GRID_SPEC_KWARGS = ("grid", "in_specs", "out_specs")
 
 # Capacity-constant name tokens that must be powers of two. SLOTS /
 # STREAM / SEGMENTS are the fused-launch table capacities (docs/
@@ -59,11 +62,14 @@ def _pallas_call_sites(mod: Module) -> List[Tuple[Optional[ast.AST],
 
 
 def check_pallas_kwargs(ctx: AnalysisContext) -> List[Finding]:
-    """KL001: every pallas_call declares the full launch geometry."""
+    """KL001: every pallas_call declares the full launch geometry
+    (``grid``/``in_specs``/``out_specs`` directly or in a ``grid_spec``)."""
     findings: List[Finding] = []
     for mod in ctx.modules:
         for _, call in _pallas_call_sites(mod):
             present = {kw.arg for kw in call.keywords if kw.arg}
+            if "grid_spec" in present:
+                present.update(GRID_SPEC_KWARGS)
             missing = [k for k in REQUIRED_KWARGS if k not in present]
             if missing:
                 findings.append(Finding(
@@ -129,13 +135,19 @@ def check_static_block_shapes(ctx: AnalysisContext) -> List[Finding]:
 
 
 def check_traced_grid(ctx: AnalysisContext) -> List[Finding]:
-    """KL003: the launch grid must not capture traced Python scalars."""
+    """KL003: the launch grid (on the call or in its ``grid_spec``) must
+    not capture traced Python scalars."""
     findings: List[Finding] = []
     for mod in ctx.modules:
         consts = module_constants(mod.tree)
         for func, call in _pallas_call_sites(mod):
             env = static_env(func, consts) if func is not None else consts
-            for kw in call.keywords:
+            grid_kws = list(call.keywords) + [
+                kw for inner in ast.walk(call)
+                if isinstance(inner, ast.Call)
+                and _dotted_tail(inner.func).endswith("GridSpec")
+                for kw in inner.keywords]
+            for kw in grid_kws:
                 if kw.arg != "grid" or kw.value is None:
                     continue
                 bad = nonstatic_parts(kw.value, env)

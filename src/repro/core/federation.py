@@ -32,9 +32,9 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import enable_x64, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..compat import enable_x64, shard_map
 from ..kernels import ops as kops
 from .fragments import FragmentStore, fragment_key
 from .placement import HeatLog, Placement
@@ -52,6 +52,10 @@ from .selectors import instantiate_patterns
 # probe of a selective pattern costs a fraction of a shard pass, large
 # enough that WatDiv-scale ranges need a handful of windows.
 DEFAULT_SHARD_WINDOW = 1024
+
+# Smallest kernel tile the sharded steps launch: one 8-row sublane group,
+# however small a shard window or compacted sub-window gets.
+_MIN_TILE = 8
 
 
 def _local_brtpf(cand: jnp.ndarray, patterns: jnp.ndarray,
@@ -754,7 +758,7 @@ class FederatedStore:
         if fn is not None:
             return fn
         mesh, axis = self.mesh, self.axis
-        bt = min(kops.DEFAULT_BT, wc)
+        bt = max(min(kops.DEFAULT_BT, wc), _MIN_TILE)
 
         def step(triples, valid, pats, pat_valid, base_vec, row_sel):
             def shard_fn(cand, cand_valid, p, pv, bv, rs):
@@ -818,7 +822,7 @@ class FederatedStore:
         full triples travel back.
         """
         window = max(1, min(window, self.shard_n))
-        wp = _pow2(window)
+        wp = max(_pow2(window), _MIN_TILE)
         key = ("fused", window, segs, groups)
         fn = self._steps.get(key)
         if fn is not None:
@@ -1294,17 +1298,19 @@ class ShardedSelector:
                 chunks, rounds = _chop_spans(spans, window)
                 rfn = self.fed.lowerable_windowed_routed(
                     window, gpad, wild_cols=wild_cols)
-                page_rounds = []
                 for r in range(rounds):
                     span_lo = np.zeros((len(chunks),), dtype=np.int32)
                     span_hi = np.zeros((len(chunks),), dtype=np.int32)
                     for s, cs in enumerate(chunks):
                         if r < len(cs):
                             span_lo[s], span_hi[s] = cs[r]
-                    page_rounds.append(rfn(
+                    # read each round before the next one launches:
+                    # holding every round's outputs on the device at
+                    # once ran a v5e out of HBM on WatDiv-10M ranges
+                    pages, first, counts, cnts = rfn(
                         idx.triples, idx.valid, pats_dev, valid_dev,
                         bv_dev, jnp.asarray(span_lo),
-                        jnp.asarray(span_hi)))
+                        jnp.asarray(span_hi))
                     self.launches.append(LaunchRecord(
                         cand_streamed=window, pat_slots=gpad * mp,
                         groups=g, pruned=plan.pruned, cand_full=window))
@@ -1315,7 +1321,6 @@ class ShardedSelector:
                             self.shard_launches[s] += 1
                             self.shard_pages[s] += 1
                             self.shard_rows[s] += b - a
-                for pages, first, counts, cnts in page_rounds:
                     counts = np.asarray(counts)
                     cnt_total += np.asarray(cnts)[:, :g].sum(axis=0)
                     if count_only:

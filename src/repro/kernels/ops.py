@@ -1,9 +1,9 @@
 """Public jit'd wrappers around the Pallas kernels.
 
-Handles padding to tile multiples, backend selection (``interpret=True``
-whenever the default backend is not TPU -- this container is CPU-only and
-validates kernels in interpret mode, the TPU path is the target), and the
-jnp-side epilogues (mask -> compacted indices).
+Handles padding to tile multiples, the choice between compiling a
+kernel for the TPU and running it in Pallas interpret mode on the CPU
+(``_use_interpret``), and the jnp-side epilogues (mask -> compacted
+indices).
 """
 from __future__ import annotations
 
@@ -22,7 +22,25 @@ from .tpf_match import DEFAULT_BR, LANES, tpf_match_pallas
 
 
 def _use_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """Interpret mode on the CPU (tests, rehearsals), compiled kernels on
+    the TPU. Any other platform is an error: a silent fallback to the
+    interpreter would let a run that lost its accelerator pass."""
+    backend = jax.default_backend()
+    if backend == "cpu":
+        return True
+    if backend == "tpu":
+        return False
+    raise RuntimeError(
+        f"Pallas kernels run on 'tpu' (or 'cpu' in interpret mode), "
+        f"not on {backend!r}")
+
+
+def _kernel_dtypes():
+    """Trace a kernel with 64-bit types off. The kernels are int32
+    throughout, but the sharded path calls them under ``enable_x64`` (its
+    packed keys are int64), where index arithmetic would promote to
+    int64, which the TPU kernel compiler does not lower."""
+    return jax.enable_x64(False)
 
 
 def _pad_to(x: jnp.ndarray, mult: int, fill) -> jnp.ndarray:
@@ -59,9 +77,10 @@ def bindjoin(cand: jnp.ndarray, patterns: jnp.ndarray,
     po = _pad_to(patterns[:, 2], bm, 0)
     pv = _pad_to(pat_valid.astype(jnp.int32), bm, 0)
     if use_pallas:
-        keep, idx = bindjoin_pallas(cs, cp, co, ps, pp, po, pv,
-                                    bt=bt, bm=bm,
-                                    interpret=_use_interpret())
+        with _kernel_dtypes():
+            keep, idx = bindjoin_pallas(cs, cp, co, ps, pp, po, pv,
+                                        bt=bt, bm=bm,
+                                        interpret=_use_interpret())
     else:
         keep, idx = ref.bindjoin_ref(cs, cp, co, ps, pp, po, pv)
         keep = keep.astype(jnp.int32)
@@ -109,9 +128,10 @@ def bindjoin_grouped(cand: jnp.ndarray, patterns: jnp.ndarray,
     po = pad_flat(patterns[:, :, 2], 0)
     pv = pad_flat(pat_valid.astype(jnp.int32), 0)
     if use_pallas:
-        keep, idx, nmatch = bindjoin_grouped_pallas(
-            cs, cp, co, ps, pp, po, pv, groups=g, bt=bt, bm=bm,
-            interpret=_use_interpret())
+        with _kernel_dtypes():
+            keep, idx, nmatch = bindjoin_grouped_pallas(
+                cs, cp, co, ps, pp, po, pv, groups=g, bt=bt, bm=bm,
+                interpret=_use_interpret())
     else:
         keep, idx, nmatch = ref.bindjoin_grouped_ref(
             cs, cp, co, ps.reshape(g, mp), pp.reshape(g, mp),
@@ -156,10 +176,11 @@ def bindjoin_fused(cand: jnp.ndarray, seg_of_tile: jnp.ndarray,
     po = pad_flat(patterns[:, :, :, 2], 0)
     pv = pad_flat(pat_valid.astype(jnp.int32), 0)
     if use_pallas:
-        keep, idx, nmatch = bindjoin_fused_pallas(
-            seg_of_tile.astype(jnp.int32), cand[:, 0], cand[:, 1],
-            cand[:, 2], ps, pp, po, pv, segments=s, groups=g, bt=bt, bm=bm,
-            interpret=_use_interpret())
+        with _kernel_dtypes():
+            keep, idx, nmatch = bindjoin_fused_pallas(
+                seg_of_tile.astype(jnp.int32), cand[:, 0], cand[:, 1],
+                cand[:, 2], ps, pp, po, pv, segments=s, groups=g, bt=bt,
+                bm=bm, interpret=_use_interpret())
     else:
         seg_of_row = jnp.repeat(seg_of_tile.astype(jnp.int32), bt)
         keep, idx, nmatch = ref.bindjoin_fused_ref(
@@ -186,8 +207,9 @@ def tpf_match(cand: jnp.ndarray, pattern_vec: jnp.ndarray, *,
     cp = _pad_to(cand[:, 1], tile, -2)   # s != p for padding rows ->
     co = _pad_to(cand[:, 2], tile, -3)   # eq_* constraints reject them
     if use_pallas:
-        mask = tpf_match_pallas(cs, cp, co, pattern_vec, br=br,
-                                interpret=_use_interpret())
+        with _kernel_dtypes():
+            mask = tpf_match_pallas(cs, cp, co, pattern_vec, br=br,
+                                    interpret=_use_interpret())
     else:
         mask = ref.tpf_match_ref(cs, cp, co, pattern_vec).astype(jnp.int32)
     return mask[:t].astype(bool)

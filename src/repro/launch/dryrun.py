@@ -27,7 +27,6 @@ import traceback
 import jax
 import jax.numpy as jnp
 
-from repro.compat import cost_analysis
 from repro.configs.base import (ALL_SHAPES, all_archs, get_arch,
                                 shapes_for, skipped_shapes_for)
 from repro.launch import roofline as RL
@@ -114,7 +113,7 @@ def lower_cell(arch_name: str, shape_name: str, multi_pod: bool,
     t_compile = time.time() - t0
 
     mem = compiled.memory_analysis()
-    cost = cost_analysis(compiled)
+    cost = compiled.cost_analysis() or {}
     hlo_text = compiled.as_text()
 
     rl = RL.analyze_compiled(

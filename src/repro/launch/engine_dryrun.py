@@ -24,7 +24,7 @@ import time
 import jax
 import jax.numpy as jnp
 
-from repro.compat import enable_x64
+from jax import enable_x64
 from repro.core.federation import FederatedStore
 from repro.launch import roofline as RL
 from repro.launch.mesh import make_production_mesh

@@ -12,6 +12,9 @@
 * ``repro.serving.faults`` -- deterministic seeded fault injection
   (delay / error / drop / stall / crash) for chaos tests and
   ``benchmarks/chaos.py``.
+* ``repro.serving.smoke`` -- the served-path check ``chip_smoke.py``
+  runs on the chip: concurrent clients over the ASGI app, every answer
+  held to the numpy oracle.
 * ``repro.serving.engine`` -- the LM serving engine (jax; imported
   lazily so the brTPF edge stays usable without an accelerator stack).
 """

@@ -21,8 +21,6 @@ from typing import Dict, Optional, Sequence, Tuple, Union
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..compat import tree_flatten_with_path
-
 MeshAxes = Union[None, str, Tuple[str, ...]]
 
 _state = threading.local()
@@ -159,8 +157,8 @@ def param_shardings(axes_tree, mesh: Mesh, rules: Dict[str, MeshAxes],
         return jax.tree.map(leaf, axes_tree, is_leaf=is_leaf)
     # axes_tree has tuple leaves where shapes_tree has array leaves;
     # walk shapes_tree and look up axes by path
-    flat_axes, _ = tree_flatten_with_path(axes_tree, is_leaf=is_leaf)
-    flat_shapes, treedef = tree_flatten_with_path(shapes_tree)
+    flat_axes, _ = jax.tree.flatten_with_path(axes_tree, is_leaf=is_leaf)
+    flat_shapes, treedef = jax.tree.flatten_with_path(shapes_tree)
     axes_by_path = {path: a for path, a in flat_axes}
     out = [leaf(axes_by_path.get(path), s) for path, s in flat_shapes]
     return jax.tree.unflatten(treedef, out)

@@ -7,7 +7,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.train import checkpoint as ckpt
 from repro.train.grad_compress import (compress_with_feedback,
                                        compressed_psum_tree, dequantize,
