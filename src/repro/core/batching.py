@@ -48,6 +48,7 @@ import asyncio
 import dataclasses
 from typing import List, Optional, Sequence, Tuple
 
+from .metrics import phase
 from .selectors import Fragment
 from .server import BrTPFServer, Request
 
@@ -92,6 +93,12 @@ class BatchStats:
     coalesced_requests: int = 0  # requests sharing a flush with >= 1 other
     max_batch_seen: int = 0
     shed: int = 0               # expired-deadline requests shed unserved
+    # Host time, ns (phase(); docs/serving.md "GET /metrics"):
+    flush_ns: int = 0           # handle_batch calls, as dispatched
+    queue_wait_ns: int = 0      # batched requests: enqueue -> dispatch
+    timer_late_ns: int = 0      # window timer firings past their due time
+    resident_ns: int = 0        # resident pages served on the loop
+    wire_ns: int = 0            # wire decode/encode (charged by the app)
 
     @property
     def mean_batch(self) -> float:
@@ -131,8 +138,9 @@ class AsyncBrTPFServer:
         self.queue_depth = queue_depth
         self.stats = BatchStats()
         self._executor = executor
+        # (request, future, deadline, enqueued at), on the loop clock
         self._pending: List[Tuple[Request, "asyncio.Future",
-                                  Optional[float]]] = []
+                                  Optional[float], float]] = []
         self._timer: Optional[asyncio.TimerHandle] = None
         self._flush_lock = asyncio.Lock()
         self._closed = False
@@ -209,7 +217,8 @@ class AsyncBrTPFServer:
         if self.server.page_resident(req):
             async with self._flush_lock:
                 self.stats.fast_path += 1
-                return self.server.handle(req)
+                with phase(self.stats, "resident"):
+                    return self.server.handle(req)
         # Admission control (docs/serving.md): refuse instead of
         # buffering without bound -- the queue drains within one
         # batching window, so the client can retry after backoff.
@@ -226,7 +235,7 @@ class AsyncBrTPFServer:
         # window is shed instead of joining the launch.
         expires = (None if req.timeout_ms is None
                    else loop.time() + req.timeout_ms / 1e3)
-        self._pending.append((req, fut, expires))
+        self._pending.append((req, fut, expires, loop.time()))
         self.stats.requests += 1
         if self.batch_window_s <= 0 or len(self._pending) >= self.max_batch:
             cause = ("full" if len(self._pending) >= self.max_batch
@@ -254,14 +263,21 @@ class AsyncBrTPFServer:
 
     # -- flush machinery -----------------------------------------------------
 
+    def _serve_batch(self, reqs: List[Request]) -> List[Fragment]:
+        with phase(self.stats, "flush"):
+            return self.server.handle_batch(reqs)
+
     def _cancel_timer(self) -> None:
         if self._timer is not None:
             self._timer.cancel()
             self._timer = None
 
     def _on_timer(self, loop) -> None:
+        # how late the loop ran the timer: its lag behind the window
+        late_s = loop.time() - self._timer.when()
         self._timer = None
         if self._pending:
+            self.stats.timer_late_ns += max(int(late_s * 1e9), 0)
             loop.create_task(self._flush("timer"))
 
     async def _flush(self, cause: str) -> None:
@@ -287,7 +303,7 @@ class AsyncBrTPFServer:
             loop = asyncio.get_running_loop()
             now = loop.time()
             batch = []
-            for req, fut, expires in taken:
+            for req, fut, expires, enqueued in taken:
                 if expires is not None and now >= expires:
                     self.stats.shed += 1
                     if not fut.done():
@@ -296,6 +312,7 @@ class AsyncBrTPFServer:
                             f"{(now - expires) * 1e3:.1f}ms before flush "
                             f"(timeout_ms={req.timeout_ms})"))
                     continue
+                self.stats.queue_wait_ns += int((now - enqueued) * 1e9)
                 batch.append((req, fut))
             if not batch:
                 return
@@ -312,9 +329,9 @@ class AsyncBrTPFServer:
             try:
                 if self._executor is not None:
                     frags = await loop.run_in_executor(
-                        self._executor, self.server.handle_batch, reqs)
+                        self._executor, self._serve_batch, reqs)
                 else:
-                    frags = self.server.handle_batch(reqs)
+                    frags = self._serve_batch(reqs)
             except Exception as exc:
                 for _, fut in batch:
                     if not fut.done():

@@ -37,6 +37,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..kernels import ops as kops
 from .fragments import FragmentStore, fragment_key
+from .metrics import Counters, phase
 from .placement import HeatLog, Placement
 from .kernel_selectors import (_EMPTY, FUSED_BT, FusedSegment,
                                LaunchRecord, _fused_base_mask,
@@ -1017,14 +1018,21 @@ class ShardedSelector:
     outright. With ``store`` connected and ``fast_path_rows`` > 0,
     plans whose relevant row count falls below the threshold are served
     by the numpy block evaluation instead of launching windows.
+
+    Host phases are charged to ``counters`` only around the helpers
+    shared with the single-host selector: ``gather`` (the pattern
+    grids), ``order`` (the numpy block evaluation and ``stream_order``).
+    Planning, the window launches and their read-back are not timed.
     """
 
     def __init__(self, fed: FederatedStore,
                  window: int = DEFAULT_SHARD_WINDOW,
                  fragments: Optional[FragmentStore] = None,
                  store=None, fast_path_rows: int = 0,
-                 heat: Optional[HeatLog] = None) -> None:
+                 heat: Optional[HeatLog] = None,
+                 counters: Optional[Counters] = None) -> None:
         self.fed = fed
+        self.counters = counters if counters is not None else Counters()
         self.window = max(1, min(int(window), fed.shard_n))
         self.fragments = fragments
         self.store = store
@@ -1264,15 +1272,17 @@ class ShardedSelector:
                 cand_streamed=int(block.shape[0]), pat_slots=0, groups=g,
                 pruned=plan.pruned, cand_full=plan.range_rows,
                 fast_path=True))
-            return select_block_numpy(block, tp, patterns,
-                                      count_only=count_only)
+            with phase(self.counters, "order"):
+                return select_block_numpy(block, tp, patterns,
+                                          count_only=count_only)
 
         # pad the grid to bucketed static shapes (bounded jit cache):
         # groups to a power of two, pattern slots to the kernel m-tile.
         gpad = _pow2(g)
         mp = kops.padded_pattern_slots(m)
-        pats, valid, base_vec = marshal_pattern_grid(tp, patterns,
-                                                     gpad, mp)
+        with phase(self.counters, "gather"):
+            pats, valid, base_vec = marshal_pattern_grid(tp, patterns,
+                                                         gpad, mp)
         comps = tp.as_tuple()
         wild = [i for i, c in enumerate(comps) if is_var(c)]
         wild_cols = tuple(wild) or (0,)  # dummy column when fully bound
@@ -1392,8 +1402,9 @@ class ShardedSelector:
                     full[:, i] = proj[:, wild.index(i)]
                 else:
                     full[:, i] = c
-            out.append((stream_order(full, first_g, patterns[gi]),
-                        int(cnt_total[gi])))
+            with phase(self.counters, "order"):
+                kept_g = stream_order(full, first_g, patterns[gi])
+            out.append((kept_g, int(cnt_total[gi])))
         return out
 
     # -- cross-pattern fusion (docs/fusion.md) -------------------------------
@@ -1444,8 +1455,9 @@ class ShardedSelector:
                     cand_streamed=int(block.shape[0]), pat_slots=0,
                     groups=len(live), pruned=plan.pruned,
                     cand_full=plan.range_rows, fast_path=True))
-                fresh = select_block_numpy(block, seg.tp, pats_live,
-                                           count_only=seg.count_only)
+                with phase(self.counters, "order"):
+                    fresh = select_block_numpy(block, seg.tp, pats_live,
+                                               count_only=seg.count_only)
                 finish_segment(self.fragments, seg, omegas_live, fresh,
                                results[si], live)
                 continue
@@ -1504,8 +1516,9 @@ class ShardedSelector:
         lo = np.zeros((s_pad,), dtype=np.int64)
         hi = np.full((s_pad,), -1, dtype=np.int64)  # empty range for pads
         for wi, (si, pats_live, _om, _live, plan) in enumerate(items):
-            p_grid, v_grid, bv = marshal_pattern_grid(
-                segments[si].tp, pats_live, g_pad, mp)
+            with phase(self.counters, "gather"):
+                p_grid, v_grid, bv = marshal_pattern_grid(
+                    segments[si].tp, pats_live, g_pad, mp)
             pats_all[wi], valid_all[wi], base_vecs[wi] = p_grid, v_grid, bv
             lo[wi], hi[wi] = plan.lo_key, plan.hi_key
         fn = self.fed.lowerable_windowed_fused(window, s_pad, g_pad)
@@ -1573,7 +1586,8 @@ class ShardedSelector:
                     continue
                 full = np.concatenate(rows, axis=0)
                 first_g = np.concatenate(firsts[(wi, gi)], axis=0)
-                fresh.append((stream_order(full, first_g,
-                                           pats_live[gi]), cnt))
+                with phase(self.counters, "order"):
+                    kept_g = stream_order(full, first_g, pats_live[gi])
+                fresh.append((kept_g, cnt))
             finish_segment(self.fragments, seg, omegas_live, fresh,
                            results[si], live)

@@ -59,6 +59,7 @@ import numpy as np
 
 from ..kernels import ops as kops
 from .fragments import FragmentStore, fragment_key
+from .metrics import Counters, phase
 from .rdf import TriplePattern, is_var
 from .selectors import instantiate_patterns
 from .store import _ORDERS, TripleStore, _pack
@@ -347,6 +348,23 @@ def stream_order(kept: np.ndarray, first: np.ndarray,
     return kept[np.lexsort((sortkey, first))]
 
 
+def to_device(counters: Counters, *arrays: np.ndarray) -> tuple:
+    """Copy one launch's host inputs to the device (phase ``h2d``),
+    counting the bytes sent in ``counters.h2d_bytes``."""
+    with phase(counters, "h2d"):
+        counters.h2d_bytes += sum(int(a.nbytes) for a in arrays)
+        return tuple(jnp.asarray(a) for a in arrays)
+
+
+def to_host(counters: Counters, *arrays) -> tuple:
+    """Bring one launch's outputs back (phase ``pull``: the wait for the
+    device and the copy), counting the bytes in ``counters.d2h_bytes``."""
+    with phase(counters, "pull"):
+        out = tuple(np.asarray(a) for a in arrays)
+        counters.d2h_bytes += sum(int(a.nbytes) for a in out)
+        return out
+
+
 def consult_fragments(
     fragments: Optional[FragmentStore], tp: TriplePattern,
     omegas: Sequence[Optional[np.ndarray]],
@@ -509,14 +527,20 @@ class KernelSelector:
     selections whose (post-pruning) candidate count falls below the
     threshold to the numpy oracle (no launch; recorded in
     :class:`LaunchRecord`).
+
+    Host phases (``range``, ``gather``, ``h2d``, ``dispatch``, ``pull``,
+    ``order``; :func:`~repro.core.metrics.phase`) are charged to
+    ``counters``: the server's, or the selector's own.
     """
 
     def __init__(self, store: TripleStore,
                  fragments: Optional[FragmentStore] = None,
-                 fast_path_rows: int = 0) -> None:
+                 fast_path_rows: int = 0,
+                 counters: Optional[Counters] = None) -> None:
         self.store = store
         self.fragments = fragments
         self.fast_path_rows = int(fast_path_rows)
+        self.counters = counters if counters is not None else Counters()
         self.launches: List[LaunchRecord] = []
 
     # -- public API ----------------------------------------------------------
@@ -614,42 +638,40 @@ class KernelSelector:
         # sub-range union, small-work fast path. Only segments that
         # would genuinely launch join the fused stream.
         work = []
+        counters = self.counters
         for si, patterns, live in prepared:
             seg = segments[si]
             omegas_live = [seg.omegas[i] for i in live]
             pats_live = [patterns[i] for i in live]
-            rng = self.store.candidate_range(seg.tp)
-            full = len(rng)
+            with phase(counters, "range"):
+                rng = self.store.candidate_range(seg.tp)
+                full = len(rng)
+                if full:
+                    all_insts = [p for group in pats_live for p in group]
+                    sr = self.store.subranges(seg.tp, insts=all_insts)
             if full == 0:
                 for i in live:
                     results[si][i] = (_EMPTY, 0)
                 continue
-            all_insts = [p for group in pats_live for p in group]
-            sr = self.store.subranges(seg.tp, insts=all_insts)
             pruned = sr is not None and sr.rows < full
-            block = None
-            if pruned:
-                block = self.store.gather_subranges(sr)
-                t = int(block.shape[0])
-                if t == 0:
-                    for i in live:
-                        results[si][i] = (_EMPTY, 0)
-                    continue
-            else:
-                t = full
-            if 0 < t <= self.fast_path_rows:
+            with phase(counters, "gather"):
+                block = (self.store.gather_subranges(sr) if pruned
+                         else rng.triples)
+            t = int(block.shape[0])
+            if t == 0:
+                for i in live:
+                    results[si][i] = (_EMPTY, 0)
+                continue
+            if t <= self.fast_path_rows:
                 self.launches.append(LaunchRecord(
                     cand_streamed=t, pat_slots=0, groups=len(live),
                     pruned=pruned, cand_full=full, fast_path=True))
-                if block is None:
-                    block = rng.triples
-                fresh = select_block_numpy(block, seg.tp, pats_live,
-                                           count_only=seg.count_only)
+                with phase(counters, "order"):
+                    fresh = select_block_numpy(block, seg.tp, pats_live,
+                                               count_only=seg.count_only)
                 self._finish_segment(seg, omegas_live, fresh,
                                      results[si], live)
                 continue
-            if block is None:
-                block = rng.triples
             work.append((si, pats_live, omegas_live, live, block, t,
                          pruned, full))
 
@@ -685,37 +707,42 @@ class KernelSelector:
 
         tiles_pad = _pow2_at_least(total_tiles)
         t_pad = tiles_pad * bt
-        cand = np.zeros((t_pad, 3), dtype=np.int32)
-        row_valid = np.zeros((t_pad,), dtype=bool)
-        seg_of_tile = np.full((tiles_pad,), -1, dtype=np.int32)
-        pats_all = np.full((s_pad, g_pad, m_max, 3), -1, dtype=np.int32)
-        valid_all = np.zeros((s_pad, g_pad, m_max), dtype=np.int32)
-        base_vecs = np.zeros((s_pad, 8), dtype=np.int32)
-        cursor = 0
-        for wi, (si, pats_live, _om, _live, block, t, _pr, _full) \
-                in enumerate(work):
-            cand[cursor:cursor + t] = block
-            row_valid[cursor:cursor + t] = True
-            seg_of_tile[cursor // bt:cursor // bt + tiles[wi]] = wi
-            p_grid, v_grid, bv = marshal_pattern_grid(
-                segments[si].tp, pats_live, g_pad, m_max)
-            pats_all[wi] = p_grid
-            valid_all[wi] = v_grid
-            base_vecs[wi] = bv
-            cursor += tiles[wi] * bt
-        seg_of_row = np.repeat(seg_of_tile, bt)
-        seg_onehot = (seg_of_row[:, None]
-                      == np.arange(s_pad)[None, :]).astype(np.int32)
+        with phase(counters, "gather"):
+            cand = np.zeros((t_pad, 3), dtype=np.int32)
+            row_valid = np.zeros((t_pad,), dtype=bool)
+            seg_of_tile = np.full((tiles_pad,), -1, dtype=np.int32)
+            pats_all = np.full((s_pad, g_pad, m_max, 3), -1,
+                               dtype=np.int32)
+            valid_all = np.zeros((s_pad, g_pad, m_max), dtype=np.int32)
+            base_vecs = np.zeros((s_pad, 8), dtype=np.int32)
+            cursor = 0
+            for wi, (si, pats_live, _om, _live, block, t, _pr, _full) \
+                    in enumerate(work):
+                cand[cursor:cursor + t] = block
+                row_valid[cursor:cursor + t] = True
+                seg_of_tile[cursor // bt:cursor // bt + tiles[wi]] = wi
+                p_grid, v_grid, bv = marshal_pattern_grid(
+                    segments[si].tp, pats_live, g_pad, m_max)
+                pats_all[wi] = p_grid
+                valid_all[wi] = v_grid
+                base_vecs[wi] = bv
+                cursor += tiles[wi] * bt
+            seg_of_row = np.repeat(seg_of_tile, bt)
+            seg_onehot = (seg_of_row[:, None]
+                          == np.arange(s_pad)[None, :]).astype(np.int32)
 
-        keep, idx, nmatch = kops.bindjoin_fused(
-            jnp.asarray(cand), jnp.asarray(seg_of_tile),
-            jnp.asarray(pats_all), jnp.asarray(valid_all), bt=bt)
-        base_mask = _fused_base_mask(
-            jnp.asarray(cand), jnp.asarray(seg_of_row),
-            jnp.asarray(base_vecs))
-        rows, seg_counts, seg_cnts = _fused_epilogue(
-            keep, nmatch, base_mask, jnp.asarray(row_valid),
-            jnp.asarray(seg_onehot), capacity=t_pad)
+        # one copy of cand per consumer: the kernel and the base mask
+        (cand_k, seg_tile_d, pats_d, valid_d, cand_m, seg_row_d, bvs_d,
+         row_valid_d, onehot_d) = to_device(
+            counters, cand, seg_of_tile, pats_all, valid_all, cand,
+            seg_of_row, base_vecs, row_valid, seg_onehot)
+        with phase(counters, "dispatch"):
+            keep, idx, nmatch = kops.bindjoin_fused(
+                cand_k, seg_tile_d, pats_d, valid_d, bt=bt)
+            base_mask = _fused_base_mask(cand_m, seg_row_d, bvs_d)
+            rows, seg_counts, seg_cnts = _fused_epilogue(
+                keep, nmatch, base_mask, row_valid_d, onehot_d,
+                capacity=t_pad)
 
         full_tiles = sum(-(-w[7] // bt) for w in work)
         self.launches.append(LaunchRecord(
@@ -726,10 +753,8 @@ class KernelSelector:
             segments=s, cand_rows=sum(w[5] for w in work),
             full_rows=sum(w[7] for w in work)))
 
-        rows = np.asarray(rows)
-        seg_counts = np.asarray(seg_counts)
-        seg_cnts = np.asarray(seg_cnts)
-        idx = np.asarray(idx)
+        rows, seg_counts, seg_cnts, idx = to_host(
+            counters, rows, seg_counts, seg_cnts, idx)
         # Column g's compacted indices ascend, and segments own disjoint
         # ascending row extents: segment wi's run starts after every
         # earlier segment's kept count in that column.
@@ -738,17 +763,18 @@ class KernelSelector:
                 in enumerate(work):
             seg = segments[si]
             fresh: List[Tuple[np.ndarray, int]] = []
-            for gi in range(len(live)):
-                cnt = int(seg_cnts[wi, gi])
-                n = int(seg_counts[wi, gi])
-                if seg.count_only or n == 0:
-                    fresh.append((_EMPTY, cnt))
-                    continue
-                kept_rows = rows[gi, off[wi, gi]:off[wi, gi] + n]
-                kept = cand[kept_rows]             # tp-index order
-                first = idx[kept_rows, gi]
-                fresh.append((stream_order(kept, first, pats_live[gi]),
-                              cnt))
+            with phase(counters, "order"):
+                for gi in range(len(live)):
+                    cnt = int(seg_cnts[wi, gi])
+                    n = int(seg_counts[wi, gi])
+                    if seg.count_only or n == 0:
+                        fresh.append((_EMPTY, cnt))
+                        continue
+                    kept_rows = rows[gi, off[wi, gi]:off[wi, gi] + n]
+                    kept = cand[kept_rows]             # tp-index order
+                    first = idx[kept_rows, gi]
+                    fresh.append((stream_order(kept, first,
+                                               pats_live[gi]), cnt))
             self._finish_segment(seg, omegas_live, fresh, results[si],
                                  live)
         return results
@@ -772,45 +798,42 @@ class KernelSelector:
         patterns: List[List[TriplePattern]], count_only: bool = False,
     ) -> List[Tuple[np.ndarray, int]]:
         """One grouped kernel launch over the store-miss groups."""
-        rng = self.store.candidate_range(tp)
-        full = len(rng)
-        if full == 0:
-            return [(_EMPTY, 0)] * len(omegas)
-
         g = len(omegas)
-
+        counters = self.counters
         # Omega-restricted pruning: the union of the groups' per-binding
         # sub-ranges covers every triple that can match any instantiated
         # pattern, so streaming only that union is exact. The flat
         # (cross-group) instantiation list keeps the grouped geometry:
         # one candidate block still serves all G requests.
-        all_insts = [p for group in patterns for p in group]
-        sr = self.store.subranges(tp, insts=all_insts)
+        with phase(counters, "range"):
+            rng = self.store.candidate_range(tp)
+            full = len(rng)
+            if full:
+                all_insts = [p for group in patterns for p in group]
+                sr = self.store.subranges(tp, insts=all_insts)
+        if full == 0:
+            return [(_EMPTY, 0)] * g
         pruned = sr is not None and sr.rows < full
-        if pruned:
-            block = self.store.gather_subranges(sr)
-            t = int(block.shape[0])
-            if t == 0:
-                # no binding has any candidates (e.g. Omega values
-                # absent from the store): nothing to stream, cnt = 0
-                return [(_EMPTY, 0)] * len(omegas)
-        else:
-            t = full
+        with phase(counters, "gather"):
+            block = self.store.gather_subranges(sr) if pruned \
+                else rng.triples
+        t = int(block.shape[0])
+        if t == 0:
+            # no binding has any candidates (e.g. Omega values absent
+            # from the store): nothing to stream, cnt = 0
+            return [(_EMPTY, 0)] * g
 
         # Small-work fast path: below the threshold the kernel cannot
         # pay its dispatch overhead -- serve the groups from the numpy
         # oracle and record the decision (no kernel launch charged).
-        if 0 < t <= self.fast_path_rows:
+        if t <= self.fast_path_rows:
             self.launches.append(LaunchRecord(
                 cand_streamed=t, pat_slots=0, groups=g,
                 pruned=pruned, cand_full=full, fast_path=True))
-            if not pruned:
-                block = rng.triples
-            return select_block_numpy(block, tp, patterns,
-                                      count_only=count_only)
+            with phase(counters, "order"):
+                return select_block_numpy(block, tp, patterns,
+                                          count_only=count_only)
 
-        if not pruned:
-            block = rng.triples
         return self._launch_block(tp, patterns, block, t, pruned, full,
                                   count_only=count_only)
 
@@ -828,18 +851,33 @@ class KernelSelector:
         """
         g = len(patterns)
         m = max(len(p) for p in patterns)
-        pats, valid, base_vec = marshal_pattern_grid(tp, patterns, g, m)
+        counters = self.counters
+        with phase(counters, "gather"):
+            pats, valid, base_vec = marshal_pattern_grid(tp, patterns,
+                                                         g, m)
+            # Pad the candidate block to a shape bucket (bounded jit
+            # cache).
+            tpad = _bucket(t)
+            cand = np.zeros((tpad, 3), dtype=np.int32)
+            cand[:t] = block
+            row_valid = np.zeros((tpad,), dtype=bool)
+            row_valid[:t] = True
 
-        # Pad the candidate block to a shape bucket (bounded jit cache).
-        tpad = _bucket(t)
-        cand = np.zeros((tpad, 3), dtype=np.int32)
-        cand[:t] = block
-        row_valid = np.zeros((tpad,), dtype=bool)
-        row_valid[:t] = True
-
-        keep, idx, nmatch = kops.bindjoin_grouped(
-            jnp.asarray(cand), jnp.asarray(pats), jnp.asarray(valid))
-        base_mask = kops.tpf_match(jnp.asarray(cand), jnp.asarray(base_vec))
+        # one copy of cand per consumer: the kernel and the base mask
+        cand_k, pats_d, valid_d, cand_m, base_vec_d, row_valid_d = \
+            to_device(counters, cand, pats, valid, cand, base_vec,
+                      row_valid)
+        with phase(counters, "dispatch"):
+            keep, idx, nmatch = kops.bindjoin_grouped(cand_k, pats_d,
+                                                      valid_d)
+            base_mask = kops.tpf_match(cand_m, base_vec_d)
+            if count_only:
+                cnts = _count_epilogue(keep, nmatch, base_mask,
+                                       row_valid_d)
+            else:
+                rows, counts, cnts = _compact_epilogue(
+                    keep, idx, nmatch, base_mask, row_valid_d,
+                    capacity=tpad)
 
         mp = kops.padded_pattern_slots(m)
         self.launches.append(
@@ -848,28 +886,24 @@ class KernelSelector:
                          cand_rows=t, full_rows=full))
 
         if count_only:
-            cnts = _count_epilogue(keep, nmatch, base_mask,
-                                   jnp.asarray(row_valid))
-            return [(_EMPTY, int(c)) for c in np.asarray(cnts)]
+            (cnts,) = to_host(counters, cnts)
+            return [(_EMPTY, int(c)) for c in cnts]
 
-        rows, counts, cnts = _compact_epilogue(
-            keep, idx, nmatch, base_mask, jnp.asarray(row_valid),
-            capacity=tpad)
-        rows = np.asarray(rows)
-        counts = np.asarray(counts)
-        cnts = np.asarray(cnts)
-        idx = np.asarray(idx)
+        rows, counts, cnts, idx = to_host(counters, rows, counts, cnts,
+                                          idx)
         out: List[Tuple[np.ndarray, int]] = []
-        for gi in range(g):
-            n = int(counts[gi])
-            if n == 0:
-                out.append((_EMPTY, int(cnts[gi])))
-                continue
-            kept_rows = rows[gi, :n]
-            kept = cand[kept_rows]                 # tp-index order
-            first = idx[kept_rows, gi]             # first matching pattern
-            out.append((self._stream_order(kept, first, patterns[gi]),
-                        int(cnts[gi])))
+        with phase(counters, "order"):
+            for gi in range(g):
+                n = int(counts[gi])
+                if n == 0:
+                    out.append((_EMPTY, int(cnts[gi])))
+                    continue
+                kept_rows = rows[gi, :n]
+                kept = cand[kept_rows]                 # tp-index order
+                first = idx[kept_rows, gi]             # first matching pattern
+                out.append((self._stream_order(kept, first,
+                                               patterns[gi]),
+                            int(cnts[gi])))
         return out
 
     # -- ordering epilogue ---------------------------------------------------

@@ -23,6 +23,10 @@ the sim ``--live`` loop and the closed-loop load generator read the
 same keys over the wire as in-process. (:func:`layer_metrics` is the
 pre-PR-7 name, kept as an alias.)
 
+:func:`phase` times one host phase of the served path: a
+``brtpf.<name>`` profiler span and the integer counter ``<name>_ns`` of
+a :class:`Counters` or ``BatchStats``, from one string. Always on.
+
 :func:`latency_summary` is the shared latency-quantile schema of the
 closed-loop load generator (``benchmarks/latency.py``): p50/p95/p99
 latency in milliseconds plus ``req_per_s`` -- the SLO quantities the
@@ -31,6 +35,8 @@ latency in milliseconds plus ``req_per_s`` -- the SLO quantities the
 from __future__ import annotations
 
 import dataclasses
+import threading
+import time
 from typing import Optional, Sequence
 
 
@@ -62,6 +68,16 @@ class Counters:
     # they are descriptive shape counters, not request dispositions.
     fused_launches: int = 0         # launches serving >= 2 segments
     fused_segments: int = 0         # segments across fused launches
+    # Host phases of the selector path, ns of self time (phase()):
+    pages_ns: int = 0               # handle_batch's per-request page loop
+    range_ns: int = 0               # candidate_range + subranges
+    gather_ns: int = 0              # candidate block, padding, grids
+    h2d_ns: int = 0                 # host->device copies of launch inputs
+    h2d_bytes: int = 0              # ... bytes sent
+    dispatch_ns: int = 0            # kernel/epilogue calls (compiles too)
+    pull_ns: int = 0                # device wait + device->host copies
+    d2h_bytes: int = 0              # ... bytes brought back
+    order_ns: int = 0               # kept-row gather + stream_order
 
     def merge(self, other: "Counters") -> None:
         for f in dataclasses.fields(self):
@@ -74,6 +90,66 @@ class Counters:
     def reset(self) -> None:
         for f in dataclasses.fields(self):
             setattr(self, f.name, 0)
+
+
+class _Phase:
+    """The context manager :func:`phase` returns."""
+
+    __slots__ = ("stats", "field", "span", "outer", "nested_ns", "t0")
+
+    def __init__(self, stats, name: str) -> None:
+        self.stats = stats
+        self.field = name + "_ns"
+        self.span = _trace_annotation()("brtpf." + name)
+        self.nested_ns = 0
+
+    def __enter__(self) -> "_Phase":
+        self.outer = getattr(_open, "phase", None)
+        _open.phase = self
+        self.span.__enter__()
+        self.t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        dt = time.perf_counter_ns() - self.t0
+        self.span.__exit__(*exc)
+        _open.phase = self.outer
+        stats = self.stats
+        setattr(stats, self.field,
+                getattr(stats, self.field) + dt - self.nested_ns)
+        outer = self.outer
+        while outer is not None and outer.stats is not stats:
+            outer = outer.outer
+        if outer is not None:
+            outer.nested_ns += dt
+
+
+_open = threading.local()     # the innermost open phase, per thread
+_TraceAnnotation = None
+
+
+def _trace_annotation():
+    # imported on first use: repro.core imports without jax
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        from jax.profiler import TraceAnnotation
+        _TraceAnnotation = TraceAnnotation
+    return _TraceAnnotation
+
+
+def phase(stats, name: str) -> _Phase:
+    """Time the block as host phase ``name`` of the served path.
+
+    Opens ``jax.profiler.TraceAnnotation("brtpf." + name)`` (a span on
+    the profiler's host plane, on the device planes' clock) and adds
+    the block's ``time.perf_counter_ns()`` elapsed time to the integer
+    field ``<name>_ns`` of ``stats``. The counter is self time: a phase
+    nested inside another phase on the same ``stats`` is charged to its
+    own counter only, so the counters of one ``stats`` never overlap
+    and sum to at most the wall time. Spans nest as the blocks do. A
+    phase must not span an ``await``.
+    """
+    return _Phase(stats, name)
 
 
 METRICS_VERSION = "brtpf/v1"
@@ -150,6 +226,11 @@ def metrics_snapshot(server, batch=None) -> dict:
             "max_batch_seen": batch.max_batch_seen,
             "mean_batch": batch.mean_batch,
             "shed": batch.shed,
+            "flush_ns": batch.flush_ns,
+            "queue_wait_ns": batch.queue_wait_ns,
+            "timer_late_ns": batch.timer_late_ns,
+            "resident_ns": batch.resident_ns,
+            "wire_ns": batch.wire_ns,
         }
         out["resilience"] = resilience_section(shed=batch.shed)
     return out

@@ -54,7 +54,7 @@ from .cache import LRUCache, request_key
 from .config import (DEFAULT_MAX_MPR, DEFAULT_META_TRIPLES_PER_PAGE,
                      DEFAULT_PAGE_SIZE, ServerConfig)
 from .fragments import FragmentStore
-from .metrics import Counters, metrics_snapshot
+from .metrics import Counters, metrics_snapshot, phase
 from .rdf import TriplePattern
 from .selectors import (Fragment, brtpf_select_with_cnt,
                         instantiate_patterns, tpf_select)
@@ -195,14 +195,17 @@ class BrTPFServer:
         # Accelerated selector (kernel or sharded backend); None for the
         # paper-faithful numpy oracle. Both implementations share the
         # select_with_cnt / select_same_pattern / launches interface,
-        # and both consult the unified store before launching.
+        # and both consult the unified store before launching. They
+        # charge their host phases (metrics.phase) to self.counters.
+        self.counters = Counters()
         self._selector = None
         self._heat = None
         if config.selector_backend == "kernel":
             from .kernel_selectors import KernelSelector
             self._selector = KernelSelector(
                 store, fragments=self.fragments,
-                fast_path_rows=config.fast_path_rows)
+                fast_path_rows=config.fast_path_rows,
+                counters=self.counters)
         elif config.selector_backend == "sharded":
             from .federation import (DEFAULT_SHARD_WINDOW, FederatedStore,
                                      ShardedSelector)
@@ -224,8 +227,7 @@ class BrTPFServer:
                 window=config.shard_window or DEFAULT_SHARD_WINDOW,
                 fragments=self.fragments,
                 store=store, fast_path_rows=config.fast_path_rows,
-                heat=self._heat)
-        self.counters = Counters()
+                heat=self._heat, counters=self.counters)
         # Memo keys prefilled by the *current* handle_batch call: their
         # subsequent handle() reads are batched work, not cache skips.
         self._prefilled: set = set()
@@ -445,7 +447,8 @@ class BrTPFServer:
         for req in reqs:
             self.validate(req)
         if self._selector is None:
-            return [self.handle(r) for r in reqs]
+            with phase(self.counters, "pages"):
+                return [self.handle(r) for r in reqs]
         # A batch may carry more distinct selections than the memo cap;
         # widen it for the batch's lifetime so prefilled results are
         # still there when handle() reads them, then trim back.
@@ -453,7 +456,8 @@ class BrTPFServer:
         self.fragments.memo_capacity = cap + len(reqs)
         try:
             self._prefill_batch(reqs)
-            return [self.handle(r) for r in reqs]
+            with phase(self.counters, "pages"):
+                return [self.handle(r) for r in reqs]
         finally:
             self._prefilled = set()
             self.fragments.memo_capacity = cap

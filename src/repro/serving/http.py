@@ -12,11 +12,12 @@ gives the async front end a real HTTP boundary:
 * ``POST /fragment``  -- the same request as a brtpf/v1 ``request``
   envelope body (``core/wire.py``);
 * ``GET  /metrics``   -- the canonical metrics snapshot
-  (``core/metrics.py``), same keys over the wire as in-process, plus a
-  transport-only ``routes`` section: server-side per-endpoint latency
-  quantiles over a bounded window of recent requests, in the SAME
-  ``latency_summary()`` schema the closed-loop load generator reports
-  client-side -- so an SLO gate can read either side of the wire.
+  (``core/metrics.py``), the same keys over the wire as in-process.
+
+The fragment handler times its request decode and response encode as
+the ``wire`` phase (``core/metrics.py`` ``phase``), charged to the
+backend's ``stats`` (``BatchStats.wire_ns``; ``RouterStats.wire_ns``
+behind a router).
 
 An over-maxMpR request maps to **HTTP 414** (the paper's URL-length
 rationale for maxMpR made literal); malformed envelopes map to 400.
@@ -36,15 +37,13 @@ from __future__ import annotations
 
 import asyncio
 import json
-import time
-from collections import deque
-from typing import Deque, Dict, List, Optional, Tuple
+from typing import List, Optional, Tuple
 from urllib.parse import parse_qs
 
 from ..core.batching import (DEFAULT_BATCH_WINDOW_S, DEFAULT_MAX_BATCH,
                              AsyncBrTPFServer, DeadlineExceeded,
                              QueueSaturated)
-from ..core.metrics import latency_summary
+from ..core.metrics import phase
 from ..core.server import MaxMprExceeded
 from ..core.wire import (WIRE_VERSION, KIND_REQUEST, WireError, dumps,
                          envelope, error_to_wire, fragment_to_wire, loads,
@@ -52,64 +51,24 @@ from ..core.wire import (WIRE_VERSION, KIND_REQUEST, WireError, dumps,
 
 _JSON_HEADERS = [(b"content-type", b"application/json")]
 
-# Per-route latency window: how many recent request durations each
-# endpoint retains. Bounded so a long-lived server cannot grow metrics
-# state without bound; 2048 samples keep p99 meaningful (nearest-rank
-# needs ~100+ samples) while costing a few KiB per route.
-ROUTE_SAMPLE_CAP = 2048
-
-# Endpoints whose latency is recorded (unknown paths are not: an
-# attacker probing random URLs must not mint unbounded route labels).
+# Known endpoints: a wrong method on one is 405, anything else 404.
 _ROUTED_PATHS = ("/", "/fragment", "/metrics")
-
-
-class RouteLatency:
-    """Server-side per-endpoint latency recorder.
-
-    Keeps the last :data:`ROUTE_SAMPLE_CAP` request durations per
-    ``"METHOD /path"`` label and summarizes them through the shared
-    :func:`~repro.core.metrics.latency_summary` schema -- p50/p95/p99/
-    mean milliseconds plus ``req_per_s`` -- so ``GET /metrics`` exposes
-    the same quantile keys server-side that ``benchmarks/latency.py``
-    measures client-side. ``req_per_s`` is computed over the wall time
-    since the route's first recorded request (the SLO-relevant arrival
-    rate, not the sum of service times).
-    """
-
-    def __init__(self, cap: int = ROUTE_SAMPLE_CAP) -> None:
-        self._cap = int(cap)
-        self._samples: Dict[str, Deque[float]] = {}
-        self._started: Dict[str, float] = {}
-
-    def record(self, route: str, seconds: float, now: float) -> None:
-        window = self._samples.get(route)
-        if window is None:
-            window = self._samples[route] = deque(maxlen=self._cap)
-            self._started[route] = now - seconds
-        window.append(seconds)
-
-    def summary(self, now: Optional[float] = None) -> dict:
-        now = time.perf_counter() if now is None else now
-        return {route: latency_summary(
-                    list(window),
-                    wall_s=max(now - self._started[route], 1e-9))
-                for route, window in sorted(self._samples.items())}
 
 
 class BrTPFApp:
     """ASGI-3 application over an async brTPF backend.
 
     ``backend`` is anything with ``async handle(Request) -> Fragment``,
-    ``metrics_snapshot()``, ``note_mappings(Request)``, ``max_mpr`` and
-    ``async aclose()`` -- an :class:`~repro.core.batching.AsyncBrTPFServer`
-    (one origin) or a :class:`~repro.serving.router.ReplicaRouter`
-    (a replica fleet). Everything the handlers await is async; the
-    origin's kernel work runs inside the backend's batching flush.
+    ``metrics_snapshot()``, ``note_mappings(Request)``, ``max_mpr``,
+    ``stats`` (with a ``wire_ns`` field) and ``async aclose()`` -- an
+    :class:`~repro.core.batching.AsyncBrTPFServer` (one origin) or a
+    :class:`~repro.serving.router.ReplicaRouter` (a replica fleet).
+    Everything the handlers await is async; the origin's kernel work
+    runs inside the backend's batching flush.
     """
 
     def __init__(self, backend) -> None:
         self.backend = backend
-        self.route_latency = RouteLatency()
 
     @property
     def max_mpr(self) -> int:
@@ -128,28 +87,22 @@ class BrTPFApp:
             raise RuntimeError(f"unsupported ASGI scope {scope['type']!r}")
         method = scope["method"]
         path = scope["path"]
-        start = time.perf_counter()
-        try:
-            if path == "/fragment" and method in ("GET", "POST"):
-                await self._fragment(scope, receive, send, method)
-            elif path == "/metrics" and method == "GET":
-                await self._send_json(send, 200, self._metrics())
-            elif path == "/" and method == "GET":
-                await self._send_json(send, 200, self._describe())
-            elif path in _ROUTED_PATHS:
-                await self._send_json(
-                    send, 405, error_to_wire(405, f"method {method} not "
-                                                  f"allowed on {path}",
-                                             code="METHOD_NOT_ALLOWED"))
-            else:
-                await self._send_json(
-                    send, 404, error_to_wire(404, f"unknown path {path!r}",
-                                             code="NOT_FOUND"))
-        finally:
-            if path in _ROUTED_PATHS:
-                now = time.perf_counter()
-                self.route_latency.record(f"{method} {path}",
-                                          now - start, now)
+        if path == "/fragment" and method in ("GET", "POST"):
+            await self._fragment(scope, receive, send, method)
+        elif path == "/metrics" and method == "GET":
+            await self._send_json(send, 200,
+                                  self.backend.metrics_snapshot())
+        elif path == "/" and method == "GET":
+            await self._send_json(send, 200, self._describe())
+        elif path in _ROUTED_PATHS:
+            await self._send_json(
+                send, 405, error_to_wire(405, f"method {method} not "
+                                              f"allowed on {path}",
+                                         code="METHOD_NOT_ALLOWED"))
+        else:
+            await self._send_json(
+                send, 404, error_to_wire(404, f"unknown path {path!r}",
+                                         code="NOT_FOUND"))
 
     async def _lifespan(self, receive, send) -> None:
         while True:
@@ -163,15 +116,6 @@ class BrTPFApp:
 
     # -- handlers ------------------------------------------------------------
 
-    def _metrics(self) -> dict:
-        """Backend snapshot plus the transport-only per-route latency
-        section. ``routes`` is added HERE and not in metrics_snapshot:
-        only the wire layer has routes, and the in-process snapshot
-        must stay byte-comparable across surfaces that have none."""
-        snap = self.backend.metrics_snapshot()
-        snap["routes"] = self.route_latency.summary()
-        return snap
-
     def _describe(self) -> dict:
         return envelope(
             "description",
@@ -181,11 +125,11 @@ class BrTPFApp:
 
     async def _fragment(self, scope, receive, send, method: str) -> None:
         try:
-            if method == "POST":
-                body = await self._read_body(receive)
-                req = request_from_wire(loads(body))
-            else:
+            body = (await self._read_body(receive) if method == "POST"
+                    else None)
+            with phase(self.backend.stats, "wire"):
                 req = request_from_wire(
+                    loads(body) if body is not None else
                     _query_to_request_envelope(scope["query_string"]))
         except WireError as exc:
             await self._send_json(send, 400, error_to_wire(
@@ -221,7 +165,9 @@ class BrTPFApp:
                 send, 504, error_to_wire(504, str(exc), retryable=True,
                                          code="DEADLINE_EXCEEDED"))
             return
-        await self._send_json(send, 200, fragment_to_wire(frag))
+        with phase(self.backend.stats, "wire"):
+            body = dumps(fragment_to_wire(frag))
+        await self._send_body(send, 200, body)
 
     # -- ASGI plumbing -------------------------------------------------------
 
@@ -238,7 +184,10 @@ class BrTPFApp:
 
     @staticmethod
     async def _send_json(send, status: int, obj: dict) -> None:
-        body = dumps(obj)
+        await BrTPFApp._send_body(send, status, dumps(obj))
+
+    @staticmethod
+    async def _send_body(send, status: int, body: bytes) -> None:
         await send({
             "type": "http.response.start",
             "status": status,

@@ -147,6 +147,7 @@ class RouterStats:
     failovers: int = 0       # served off the preferred replica
     replica_failures: int = 0  # infra failures charged to a breaker
     per_replica: List[int] = dataclasses.field(default_factory=list)
+    wire_ns: int = 0         # the app's decode/encode (phase "wire")
 
 
 class ReplicaRouter:
@@ -288,6 +289,7 @@ class ReplicaRouter:
                 "replicas": len(self.replicas),
                 "requests": self.stats.requests,
                 "requests_per_replica": list(self.stats.per_replica),
+                "wire_ns": self.stats.wire_ns,
             },
             "resilience": resilience_section(
                 shed=sum(s.get("resilience", {}).get("shed", 0)

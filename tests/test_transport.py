@@ -27,7 +27,6 @@ from repro.core import (AsyncBrTPFClient, BrTPFClient, BrTPFServer,
                         metrics_snapshot, request_from_wire,
                         request_to_wire)
 from repro.core.batching import AsyncBrTPFServer
-from repro.core.metrics import latency_summary
 from repro.core.wire import (ERROR_CODES, dumps, error_from_wire,
                              error_to_wire, loads)
 from repro.serving.http import TestClient, app_from_config, create_app
@@ -441,42 +440,11 @@ class TestHttpApp:
         for req in sample_requests(store, count=6, max_mpr=CFG.max_mpr):
             client.post("/fragment", json_body=req.to_wire())
         wire = client.get("/metrics").json()
-        # ``routes`` is the one transport-only section (server-side
-        # per-endpoint latency -- the in-process snapshot has no HTTP
-        # routes); everything else must match byte-for-byte.
-        routes = wire.pop("routes")
-        assert isinstance(routes, dict)
         local = json.loads(dumps(client.app.backend.metrics_snapshot()))
         assert wire == local
         assert wire["v"] == WIRE_VERSION
         assert wire["counters"]["num_requests"] == 6
         assert "batch" in wire
-
-    def test_metrics_per_route_latency_schema(self, store, client):
-        for req in sample_requests(store, count=4, max_mpr=CFG.max_mpr):
-            client.post("/fragment", json_body=req.to_wire())
-        client.get("/")
-        client.get("/metrics")
-        routes = client.get("/metrics").json()["routes"]
-        # routes recorded so far: description, fragment POSTs and the
-        # previous /metrics call (a request records after responding,
-        # so the in-flight GET /metrics is not in its own summary)
-        assert set(routes) == {"GET /", "POST /fragment", "GET /metrics"}
-        # schema stability: every route speaks the exact
-        # latency_summary() schema, nothing more, nothing less
-        expected_keys = set(latency_summary([]))
-        for route, summary in routes.items():
-            assert set(summary) == expected_keys, route
-        assert routes["POST /fragment"]["requests"] == 4
-        assert routes["GET /metrics"]["requests"] == 1
-        frag = routes["POST /fragment"]
-        assert 0.0 <= frag["p50_latency_ms"] <= frag["p95_latency_ms"] \
-               <= frag["p99_latency_ms"]
-        assert frag["req_per_s"] > 0.0
-        # bounded state: unknown paths must not mint route labels
-        client.get("/definitely-not-a-route")
-        assert set(client.get("/metrics").json()["routes"]) \
-               == {"GET /", "POST /fragment", "GET /metrics"}
 
 
 @pytest.mark.parametrize("backend,extra", [
