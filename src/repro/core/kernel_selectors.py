@@ -165,7 +165,6 @@ def fusion_legality(segments: Sequence[FusedSegment], *,
     return None
 
 
-@functools.partial(jax.jit, static_argnames=("capacity",))
 def _compact_epilogue(keep, idx_first, nmatch, base_mask, row_valid,
                       capacity: int):
     """Device epilogue over the grouped kernel outputs.
@@ -183,7 +182,6 @@ def _compact_epilogue(keep, idx_first, nmatch, base_mask, row_valid,
     return rows, counts, cnts
 
 
-@jax.jit
 def _count_epilogue(keep, nmatch, base_mask, row_valid):
     """Count-only epilogue: just the Definition-2 ``cnt`` per group.
 
@@ -213,7 +211,6 @@ def _fused_base_mask(cand, seg_of_row, base_vecs):
     return mask & (seg_of_row >= 0)
 
 
-@functools.partial(jax.jit, static_argnames=("capacity",))
 def _fused_epilogue(keep, nmatch, base_mask, row_valid, seg_onehot,
                     capacity: int):
     """Device epilogue over the fused kernel outputs.
@@ -232,6 +229,42 @@ def _fused_epilogue(keep, nmatch, base_mask, row_valid, seg_onehot,
         lambda m: kops.compact_mask(m, capacity),
         in_axes=1, out_axes=0)(mask)                            # (G, Tp)
     return rows, seg_counts, seg_cnts
+
+
+# One compiled program per launch: the kernel, the base-pattern mask and
+# the epilogue are traced together, so the padding, slicing and
+# flattening in the ``kops`` wrappers compile into the program once per
+# shape instead of dispatching as eager ops on every launch. The key is
+# the shapes alone: the candidate bucket, G, and the pattern grid's
+# power-of-two M.
+
+@functools.partial(jax.jit, static_argnames=("capacity", "count_only"))
+def _grouped_launch(cand_k, pats, valid, cand_m, base_vec, row_valid,
+                    capacity: int, count_only: bool):
+    """A grouped launch: ``bindjoin_grouped``, the base pattern's
+    ``tpf_match`` and the epilogue. Returns ``(rows, counts, cnts,
+    idx)``, or ``cnts`` alone for a count-only launch."""
+    keep, idx, nmatch = kops.bindjoin_grouped(cand_k, pats, valid)
+    base_mask = kops.tpf_match(cand_m, base_vec)
+    if count_only:
+        return _count_epilogue(keep, nmatch, base_mask, row_valid)
+    rows, counts, cnts = _compact_epilogue(keep, idx, nmatch, base_mask,
+                                           row_valid, capacity)
+    return rows, counts, cnts, idx
+
+
+@functools.partial(jax.jit, static_argnames=("capacity",))
+def _fused_launch(cand_k, seg_of_tile, pats, valid, cand_m, seg_of_row,
+                  base_vecs, row_valid, seg_onehot, capacity: int):
+    """A fused launch: ``bindjoin_fused``, the per-segment base mask and
+    the segment-aware epilogue. Returns ``(rows, seg_counts, seg_cnts,
+    idx)``."""
+    keep, idx, nmatch = kops.bindjoin_fused(cand_k, seg_of_tile, pats,
+                                            valid, bt=FUSED_BT)
+    base_mask = _fused_base_mask(cand_m, seg_of_row, base_vecs)
+    rows, seg_counts, seg_cnts = _fused_epilogue(
+        keep, nmatch, base_mask, row_valid, seg_onehot, capacity)
+    return rows, seg_counts, seg_cnts, idx
 
 
 @dataclasses.dataclass
@@ -679,13 +712,14 @@ class KernelSelector:
             return results
 
         # Fused geometry: common padded (G, Mp) slot grid, power-of-two
-        # segment/tile counts (bounded jit cache), per-segment blocks
-        # tile-aligned so every bt-tile belongs to exactly one segment.
+        # segment/tile/pattern counts (bounded jit cache), per-segment
+        # blocks tile-aligned so every bt-tile belongs to exactly one
+        # segment.
         bt = FUSED_BT
         s = len(work)
         s_pad = _pow2_at_least(s)
         g_pad = _pow2_at_least(max(len(w[3]) for w in work))
-        m_max = max(max(len(p) for p in w[1]) for w in work)
+        m_max = _pow2_at_least(max(len(p) for w in work for p in w[1]))
         mp = kops.padded_pattern_slots(m_max)
         tiles = [-(-w[5] // bt) for w in work]
         total_tiles = sum(tiles)
@@ -737,12 +771,9 @@ class KernelSelector:
             counters, cand, seg_of_tile, pats_all, valid_all, cand,
             seg_of_row, base_vecs, row_valid, seg_onehot)
         with phase(counters, "dispatch"):
-            keep, idx, nmatch = kops.bindjoin_fused(
-                cand_k, seg_tile_d, pats_d, valid_d, bt=bt)
-            base_mask = _fused_base_mask(cand_m, seg_row_d, bvs_d)
-            rows, seg_counts, seg_cnts = _fused_epilogue(
-                keep, nmatch, base_mask, row_valid_d, onehot_d,
-                capacity=t_pad)
+            rows, seg_counts, seg_cnts, idx = _fused_launch(
+                cand_k, seg_tile_d, pats_d, valid_d, cand_m, seg_row_d,
+                bvs_d, row_valid_d, onehot_d, capacity=t_pad)
 
         full_tiles = sum(-(-w[7] // bt) for w in work)
         self.launches.append(LaunchRecord(
@@ -850,7 +881,8 @@ class KernelSelector:
         epilogue: only the per-group Definition-2 counts come back.
         """
         g = len(patterns)
-        m = max(len(p) for p in patterns)
+        # the grid's M is padded to a power of two (bounded jit cache)
+        m = _pow2_at_least(max(len(p) for p in patterns))
         counters = self.counters
         with phase(counters, "gather"):
             pats, valid, base_vec = marshal_pattern_grid(tp, patterns,
@@ -868,16 +900,9 @@ class KernelSelector:
             to_device(counters, cand, pats, valid, cand, base_vec,
                       row_valid)
         with phase(counters, "dispatch"):
-            keep, idx, nmatch = kops.bindjoin_grouped(cand_k, pats_d,
-                                                      valid_d)
-            base_mask = kops.tpf_match(cand_m, base_vec_d)
-            if count_only:
-                cnts = _count_epilogue(keep, nmatch, base_mask,
-                                       row_valid_d)
-            else:
-                rows, counts, cnts = _compact_epilogue(
-                    keep, idx, nmatch, base_mask, row_valid_d,
-                    capacity=tpad)
+            launched = _grouped_launch(
+                cand_k, pats_d, valid_d, cand_m, base_vec_d, row_valid_d,
+                capacity=tpad, count_only=count_only)
 
         mp = kops.padded_pattern_slots(m)
         self.launches.append(
@@ -886,11 +911,10 @@ class KernelSelector:
                          cand_rows=t, full_rows=full))
 
         if count_only:
-            (cnts,) = to_host(counters, cnts)
+            (cnts,) = to_host(counters, launched)
             return [(_EMPTY, int(c)) for c in cnts]
 
-        rows, counts, cnts, idx = to_host(counters, rows, counts, cnts,
-                                          idx)
+        rows, counts, cnts, idx = to_host(counters, *launched)
         out: List[Tuple[np.ndarray, int]] = []
         with phase(counters, "order"):
             for gi in range(g):

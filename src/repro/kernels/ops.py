@@ -1,9 +1,12 @@
-"""Public jit'd wrappers around the Pallas kernels.
+"""Public wrappers around the Pallas kernels.
 
 Handles padding to tile multiples, the choice between compiling a
 kernel for the TPU and running it in Pallas interpret mode on the CPU
 (``_use_interpret``), and the jnp-side epilogues (mask -> compacted
-indices).
+indices). The wrappers are meant to be traced inside their caller's
+program (the selectors' launch programs, the sharded path's
+``shard_map`` steps): called eagerly, each of their ``jnp`` ops would
+be a host dispatch and a device program of its own.
 """
 from __future__ import annotations
 

@@ -6,13 +6,19 @@ estimate of ``selectors.brtpf_select_with_cnt``, for every pattern/omega
 shape, so that paging through ``BrTPFServer.handle`` is bit-for-bit
 independent of the selector backend.
 """
+import jax
 import numpy as np
 import pytest
 
 from repro.core import (BrTPFServer, Request, ServerConfig, TriplePattern,
                         TripleStore, UNBOUND, brtpf_select_with_cnt,
                         encode_var)
-from repro.core.kernel_selectors import KernelSelector
+from repro.core import kernel_selectors as ks
+from repro.core import metrics
+from repro.core.kernel_selectors import (FusedSegment, KernelSelector,
+                                         select_block_numpy)
+from repro.core.selectors import instantiate_patterns
+from repro.kernels import ops as kops
 
 V = encode_var
 
@@ -227,3 +233,119 @@ class TestServerBackendParity:
                 f.data, want[:server.page_size])
             assert f.cnt == wcnt
         assert server.counters.kernel_launches == 0
+
+
+def bound_omega(m, offset=0):
+    """m mappings binding ?0 to distinct terms: m instantiated patterns."""
+    om = np.full((m, 2), UNBOUND, np.int32)
+    om[:, 0] = np.arange(offset, offset + m)
+    return om
+
+
+TP_A = TriplePattern(V(0), 3, V(1))
+TP_B = TriplePattern(V(0), 5, V(1))
+
+
+def launch(sel, kind, m=3):
+    """One launch of ``kind`` whose longest group has m patterns: G = 2
+    (grouped), G = 1 (count-only), or S = 2 segments of G = 1 (fused)."""
+    if kind == "grouped":
+        return sel.select_same_pattern(TP_A, [bound_omega(m), None])
+    if kind == "count_only":
+        return sel.select_count(TP_A, bound_omega(m))
+    return sel.select_fused([FusedSegment(TP_A, [bound_omega(m)]),
+                             FusedSegment(TP_B, [None])])
+
+
+class TestLaunchProgram:
+    """Each selector launch is one compiled program, keyed by the
+    launch's shape, with the pattern grid's M padded to a power of two."""
+
+    store = make_store(21, n=3000, terms=40)
+
+    @pytest.mark.parametrize("kind", ["grouped", "count_only", "fused"])
+    def test_one_program_call_per_launch(self, kind, monkeypatch):
+        name = "_fused_launch" if kind == "fused" else "_grouped_launch"
+        program = getattr(ks, name)
+        calls = []
+
+        def spy(*args, **kwargs):
+            # the host phase the call runs in
+            calls.append(getattr(getattr(metrics._open, "phase", None),
+                                 "field", None))
+            return program(*args, **kwargs)
+
+        monkeypatch.setattr(ks, name, spy)
+        # the kernel wrappers run only while the program is traced
+        eager = []
+        for fn in ("bindjoin_grouped", "bindjoin_fused", "tpf_match"):
+            def watch(cand, *a, _fn=fn, _orig=getattr(kops, fn), **k):
+                if not isinstance(cand, jax.core.Tracer):
+                    eager.append(_fn)
+                return _orig(cand, *a, **k)
+            monkeypatch.setattr(kops, fn, watch)
+        sel = KernelSelector(self.store)
+        launch(sel, kind)
+        assert calls == ["dispatch_ns"]
+        assert eager == []
+        (rec,) = sel.launches
+        assert rec.segments == (2 if kind == "fused" else 1)
+
+    @pytest.mark.parametrize("kind", ["grouped", "count_only", "fused"])
+    def test_program_is_keyed_by_power_of_two_m(self, kind):
+        program = ks._fused_launch if kind == "fused" else ks._grouped_launch
+        program.clear_cache()
+        sel = KernelSelector(self.store)
+        sizes = []
+        for m in (3, 4, 5):
+            launch(sel, kind, m)
+            sizes.append(program._cache_size())
+        # the same candidate bucket and G for all three
+        assert len({(r.cand_streamed, r.groups, r.segments)
+                    for r in sel.launches}) == 1
+        assert sizes == [1, 1, 2]
+
+    EDGES = [pytest.param(1, True, id="tpf"),
+             *[pytest.param(m, False, id=f"m{m}") for m in (1, 3, 4, 5, 30)]]
+
+    @pytest.mark.parametrize("m,tpf", EDGES)
+    def test_grouped_matches_block_numpy(self, m, tpf):
+        """Two groups, the longer with m patterns, against the numpy
+        evaluation of the same block; count-only too."""
+        omegas = ([None, None] if tpf
+                  else [bound_omega(m), bound_omega(max(m // 2, 1), 7)])
+        patterns = [instantiate_patterns(TP_A, om) for om in omegas]
+        assert max(len(p) for p in patterns) == m
+        want = select_block_numpy(self.store.candidate_range(TP_A).triples,
+                                  TP_A, patterns)
+        sel = KernelSelector(self.store)
+        got = sel.select_same_pattern(TP_A, omegas)
+        (rec,) = sel.launches
+        assert not rec.fast_path
+        for (data, cnt), (w_data, w_cnt) in zip(got, want, strict=True):
+            assert data.dtype == w_data.dtype
+            np.testing.assert_array_equal(data, w_data)
+            assert cnt == w_cnt
+        assert KernelSelector(self.store).select_count(
+            TP_A, omegas[0]) == want[0][1]
+
+    @pytest.mark.parametrize("m,tpf", EDGES)
+    def test_fused_matches_block_numpy(self, m, tpf):
+        """Two fused segments of different M and G against the numpy
+        evaluation of each segment's block."""
+        segs = [FusedSegment(TP_A, [None if tpf else bound_omega(m)]),
+                FusedSegment(TP_B, [None, None] if tpf
+                             else [bound_omega(3, 11), bound_omega(1, 20)])]
+        sel = KernelSelector(self.store)
+        got = sel.select_fused(segs)
+        (rec,) = sel.launches
+        assert rec.segments == 2
+        for seg, res in zip(segs, got, strict=True):
+            patterns = [instantiate_patterns(seg.tp, om) for om in seg.omegas]
+            want = select_block_numpy(
+                self.store.candidate_range(seg.tp).triples, seg.tp,
+                patterns)
+            for (data, cnt), (w_data, w_cnt) in zip(res, want, strict=True):
+                assert data.dtype == w_data.dtype
+                np.testing.assert_array_equal(data, w_data)
+                assert cnt == w_cnt

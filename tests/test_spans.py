@@ -192,7 +192,8 @@ def test_h2d_and_d2h_bytes_of_a_grouped_launch():
     sel.select_same_pattern(tp, omegas)
     (rec,) = sel.launches
     tpad, g = rec.cand_streamed, rec.groups
-    m = max(len(instantiate_patterns(tp, om)) for om in omegas)
+    m = _pow2_at_least(max(len(instantiate_patterns(tp, om))
+                           for om in omegas))  # the grid's padded M
     sent = (2 * tpad * 3 * 4               # cand, to the kernel and mask
             + g * m * 3 * 4 + g * m * 4    # pattern grid and its validity
             + 8 * 4                        # base-pattern vector

@@ -16,7 +16,8 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core.federation import _MIN_TILE
-from repro.core.kernel_selectors import FUSED_BT
+from repro.core.kernel_selectors import (FUSED_BT, _fused_launch,
+                                         _grouped_launch)
 
 # The package re-exports ``bindjoin``/``tpf_match`` as functions, which
 # shadow the submodules of the same names.
@@ -48,9 +49,13 @@ def one_chip():
     jax.config.update("jax_enable_compilation_cache", enabled)
 
 
-def _compile(fn, sharding, *shapes):
-    args = [jax.ShapeDtypeStruct(s, jnp.int32, sharding=sharding)
-            for s in shapes]
+def _compile(fn, sharding, *shapes, dtypes=None):
+    """Lower ``fn`` for int32 arguments of ``shapes`` (``dtypes`` names
+    any other, by position) and compile it for the described chip."""
+    dtypes = dtypes or {}
+    args = [jax.ShapeDtypeStruct(s, dtypes.get(i, jnp.int32),
+                                 sharding=sharding)
+            for i, s in enumerate(shapes)]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
     mem = compiled.memory_analysis()
@@ -88,3 +93,43 @@ def test_bindjoin_fused_compiles(one_chip, bt):
                                             groups=groups, bt=bt),
              one_chip, (t // bt,), (t,), (t,), (t,),
              *[(segments * groups * m,)] * 4)
+
+
+# The served launch programs: each launch's kernel, base-pattern mask and
+# epilogue as one program, at the buckets a served launch meets and the
+# widths of a brTPF batch (G 16 groups of M up to 32 patterns).
+LAUNCH_G, LAUNCH_M, LAUNCH_S = 16, 32, 16
+
+
+@pytest.fixture
+def launch_chip(one_chip, monkeypatch):
+    """``one_chip``, with the launch programs tracing their kernels for
+    the TPU compiler: this process's own backend may be the CPU, where
+    ``kops`` would trace them for the Pallas interpreter. The trace
+    caches are cleared around the test, so no trace crosses over."""
+    from repro.kernels import ops as kops
+    monkeypatch.setattr(kops, "_use_interpret", lambda: False)
+    jax.clear_caches()
+    yield one_chip
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("tpad", [8192, 131072])
+@pytest.mark.parametrize("count_only", [False, True],
+                         ids=["rows", "count"])
+def test_grouped_launch_program_compiles(launch_chip, tpad, count_only):
+    g, m = LAUNCH_G, LAUNCH_M
+    _compile(lambda *a: _grouped_launch(*a, capacity=tpad,
+                                        count_only=count_only),
+             launch_chip,
+             (tpad, 3), (g, m, 3), (g, m), (tpad, 3), (8,), (tpad,),
+             dtypes={5: jnp.bool_})
+
+
+@pytest.mark.parametrize("tpad", [8192, 131072])
+def test_fused_launch_program_compiles(launch_chip, tpad):
+    s, g, m = LAUNCH_S, LAUNCH_G, LAUNCH_M
+    _compile(lambda *a: _fused_launch(*a, capacity=tpad), launch_chip,
+             (tpad, 3), (tpad // FUSED_BT,), (s, g, m, 3), (s, g, m),
+             (tpad, 3), (tpad,), (s, 8), (tpad,), (tpad, s),
+             dtypes={7: jnp.bool_})
