@@ -48,7 +48,7 @@ import asyncio
 import dataclasses
 from typing import List, Optional, Sequence, Tuple
 
-from .metrics import phase
+from .metrics import charge_mappings, phase
 from .selectors import Fragment
 from .server import BrTPFServer, Request
 
@@ -172,11 +172,12 @@ class AsyncBrTPFServer:
 
     def note_mappings(self, req: Request) -> None:
         """Charge the request's attached solution mappings to the
-        server's ``mappings_sent``. Called by the WIRE boundary
-        (transport / ASGI app) -- in-process clients charge the counter
-        themselves, so the two paths never double-count."""
+        server's ``mappings_sent`` (and a non-empty Omega to
+        ``omega_requests``). Called by the WIRE boundary (transport /
+        ASGI app) -- in-process clients charge the counters themselves,
+        so the two paths never double-count."""
         if req.omega is not None:
-            self.server.counters.mappings_sent += int(req.omega.shape[0])
+            charge_mappings(self.server.counters, req.omega)
 
     def metrics_snapshot(self) -> dict:
         """The canonical metrics envelope (metrics.py) with this front

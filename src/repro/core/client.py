@@ -25,6 +25,7 @@ import numpy as np
 
 from .bgp import BGP
 from .fragments import ClientFragmentCache
+from .metrics import charge_mappings
 from .rdf import (UNBOUND, TriplePattern, is_var, decode_var,
                   mapping_from_triple)
 from .server import BrTPFServer, Request
@@ -85,7 +86,7 @@ class _ClientBase:
             raise RequestBudgetExceeded()
         self._requests_used += 1
         if omega is not None:
-            self.server.counters.mappings_sent += int(omega.shape[0])
+            charge_mappings(self.server.counters, omega)
         before = self.server.counters.snapshot()
         shard_snap = getattr(self.server, "shard_launch_snapshot", None)
         before_shards = shard_snap() if shard_snap is not None else None
@@ -375,7 +376,7 @@ class AsyncBrTPFClient:
         # In-process accounting only: over a transport the wire boundary
         # charges mappings_sent itself (Transport/ASGI note_mappings).
         if omega is not None and self.server is not None:
-            self.server.counters.mappings_sent += int(omega.shape[0])
+            charge_mappings(self.server.counters, omega)
         frag = await self.front.handle(req)
         self._received += frag.triples_received
         self.client_cache.put(req.key(), frag)

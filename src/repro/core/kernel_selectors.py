@@ -322,6 +322,11 @@ class LaunchRecord:
     # paying (``pruned`` goes False); lets the sim cap its additive
     # union estimate at the real range size.
     full_rows: int = 0
+    # the pat_slots holding an instantiated pattern: the groups' pattern
+    # counts summed; on a fused launch, whose pat_slots is the one
+    # segment grid each candidate row meets, the mean over raw rows of
+    # the filled slots of the row's segment
+    pat_used: int = 0
 
     @property
     def cells(self) -> int:
@@ -561,9 +566,11 @@ class KernelSelector:
     threshold to the numpy oracle (no launch; recorded in
     :class:`LaunchRecord`).
 
-    Host phases (``range``, ``gather``, ``h2d``, ``dispatch``, ``pull``,
-    ``order``; :func:`~repro.core.metrics.phase`) are charged to
-    ``counters``: the server's, or the selector's own.
+    Host phases (``instantiate``, ``range``, ``prune``, ``gather``,
+    ``h2d``, ``dispatch``, ``pull``, ``order``;
+    :func:`~repro.core.metrics.phase`) are charged to ``counters``: the
+    server's, or the selector's own. ``prune`` (the sub-range lookup)
+    nests inside ``range`` and is charged to itself alone.
     """
 
     def __init__(self, store: TripleStore,
@@ -604,7 +611,8 @@ class KernelSelector:
         recorded as skipped and only the remaining groups launch.
         """
         if patterns is None:
-            patterns = [instantiate_patterns(tp, om) for om in omegas]
+            with phase(self.counters, "instantiate"):
+                patterns = [instantiate_patterns(tp, om) for om in omegas]
         results, live = consult_fragments(self.fragments, tp, omegas,
                                           self.launches)
         if live:
@@ -633,8 +641,10 @@ class KernelSelector:
                 self.launches.append(LaunchRecord(
                     cand_streamed=0, pat_slots=0, groups=1, skipped=True))
                 return int(got[1])
-        patterns = [insts if insts is not None
-                    else instantiate_patterns(tp, omega)]
+        if insts is None:
+            with phase(self.counters, "instantiate"):
+                insts = instantiate_patterns(tp, omega)
+        patterns = [insts]
         return self._launch_groups(tp, [omega], patterns,
                                    count_only=True)[0][1]
 
@@ -661,8 +671,9 @@ class KernelSelector:
         for si, seg in enumerate(segments):
             patterns = seg.patterns
             if patterns is None:
-                patterns = [instantiate_patterns(seg.tp, om)
-                            for om in seg.omegas]
+                with phase(self.counters, "instantiate"):
+                    patterns = [instantiate_patterns(seg.tp, om)
+                                for om in seg.omegas]
             live = self._consult_segment(seg, results[si])
             if live:
                 prepared.append((si, patterns, live))
@@ -681,7 +692,8 @@ class KernelSelector:
                 full = len(rng)
                 if full:
                     all_insts = [p for group in pats_live for p in group]
-                    sr = self.store.subranges(seg.tp, insts=all_insts)
+                    with phase(counters, "prune"):
+                        sr = self.store.subranges(seg.tp, insts=all_insts)
             if full == 0:
                 for i in live:
                     results[si][i] = (_EMPTY, 0)
@@ -776,12 +788,15 @@ class KernelSelector:
                 bvs_d, row_valid_d, onehot_d, capacity=t_pad)
 
         full_tiles = sum(-(-w[7] // bt) for w in work)
+        rows_total = sum(w[5] for w in work)
         self.launches.append(LaunchRecord(
             cand_streamed=t_pad, pat_slots=g_pad * mp,
+            pat_used=round(sum(w[5] * sum(len(p) for p in w[1])
+                               for w in work) / rows_total),
             groups=sum(len(w[3]) for w in work),
             pruned=any(w[6] for w in work),
             cand_full=_pow2_at_least(full_tiles) * bt,
-            segments=s, cand_rows=sum(w[5] for w in work),
+            segments=s, cand_rows=rows_total,
             full_rows=sum(w[7] for w in work)))
 
         rows, seg_counts, seg_cnts, idx = to_host(
@@ -841,7 +856,8 @@ class KernelSelector:
             full = len(rng)
             if full:
                 all_insts = [p for group in patterns for p in group]
-                sr = self.store.subranges(tp, insts=all_insts)
+                with phase(counters, "prune"):
+                    sr = self.store.subranges(tp, insts=all_insts)
         if full == 0:
             return [(_EMPTY, 0)] * g
         pruned = sr is not None and sr.rows < full
@@ -907,6 +923,7 @@ class KernelSelector:
         mp = kops.padded_pattern_slots(m)
         self.launches.append(
             LaunchRecord(cand_streamed=tpad, pat_slots=g * mp, groups=g,
+                         pat_used=sum(len(p) for p in patterns),
                          pruned=pruned, cand_full=_bucket(full),
                          cand_rows=t, full_rows=full))
 

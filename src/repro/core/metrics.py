@@ -50,12 +50,15 @@ class Counters:
     server_lookups: int = 0         # index lookups performed by the server
     server_triples_scanned: int = 0
     mappings_sent: int = 0          # solution mappings attached to requests
+    omega_requests: int = 0         # requests carrying a non-empty Omega
     # kernel-selector launch accounting (selector_backend="kernel"):
     kernel_launches: int = 0        # grouped bind-join kernel launches
     kernel_cand_streamed: int = 0   # padded candidates streamed (HBM pass)
     kernel_cand_rows: int = 0       # raw (pre-padding) candidate rows
     kernel_cand_full_rows: int = 0  # raw full-range rows behind launches
     kernel_pat_slots: int = 0       # padded pattern slots across groups
+    kernel_pat_used: int = 0        # ... of them holding an instantiated
+    #                                 pattern (LaunchRecord.pat_used)
     kernel_batched_requests: int = 0  # requests served by shared launches
     launches_skipped: int = 0       # launches avoided by store residency
     # Omega-restricted pruning / small-work fast path (docs/pruning.md):
@@ -70,7 +73,9 @@ class Counters:
     fused_segments: int = 0         # segments across fused launches
     # Host phases of the selector path, ns of self time (phase()):
     pages_ns: int = 0               # handle_batch's per-request page loop
-    range_ns: int = 0               # candidate_range + subranges
+    instantiate_ns: int = 0         # instantiate_patterns (Omega -> patterns)
+    range_ns: int = 0               # candidate_range (subranges not included)
+    prune_ns: int = 0               # subranges: Omega sub-range pruning
     gather_ns: int = 0              # candidate block, padding, grids
     h2d_ns: int = 0                 # host->device copies of launch inputs
     h2d_bytes: int = 0              # ... bytes sent
@@ -90,6 +95,14 @@ class Counters:
     def reset(self) -> None:
         for f in dataclasses.fields(self):
             setattr(self, f.name, 0)
+
+
+def charge_mappings(counters: Counters, omega) -> None:
+    """One request sent with ``omega`` attached: its mappings to
+    ``mappings_sent``, and the request to ``omega_requests`` if any."""
+    n = int(omega.shape[0])
+    counters.mappings_sent += n
+    counters.omega_requests += n > 0
 
 
 class _Phase:

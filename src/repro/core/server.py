@@ -319,7 +319,8 @@ class BrTPFServer:
         if req.count_only:
             return self._count_data(req, memo_key)
         if req.is_brtpf:
-            patterns = instantiate_patterns(req.pattern, req.omega)
+            with phase(self.counters, "instantiate"):
+                patterns = instantiate_patterns(req.pattern, req.omega)
             self.counters.server_lookups += len(patterns)
             if self._selector is not None:
                 data, cnt = self._select_kernel(req.pattern, req.omega,
@@ -345,7 +346,8 @@ class BrTPFServer:
         evaluates; the gather/stream epilogue is skipped); the numpy
         oracle uses ``brtpf_count`` (pure ``cardinality`` sums)."""
         omega = req.omega if req.is_brtpf else None
-        patterns = instantiate_patterns(req.pattern, omega)
+        with phase(self.counters, "instantiate"):
+            patterns = instantiate_patterns(req.pattern, omega)
         self.counters.server_lookups += len(patterns)
         if self._selector is not None:
             n0 = len(self._selector.launches)
@@ -391,6 +393,7 @@ class BrTPFServer:
             self.counters.kernel_cand_full_rows += (
                 rec.full_rows or rec.cand_rows or rec.cand_streamed)
             self.counters.kernel_pat_slots += rec.pat_slots
+            self.counters.kernel_pat_used += rec.pat_used
             if rec.segments > 1:
                 # shape classification of the launch just charged above
                 # (fused launches ARE kernel launches), feeding the
@@ -491,7 +494,8 @@ class BrTPFServer:
             tp = member_reqs[0].pattern
             omegas = [r.omega if r.is_brtpf else None
                       for r in member_reqs]
-            insts = [instantiate_patterns(tp, om) for om in omegas]
+            with phase(self.counters, "instantiate"):
+                insts = [instantiate_patterns(tp, om) for om in omegas]
             n0 = len(self._selector.launches)
             results = self._selector.select_same_pattern(
                 tp, omegas, insts)
@@ -509,7 +513,8 @@ class BrTPFServer:
             tp = member_reqs[0].pattern
             omegas = [r.omega if r.is_brtpf else None
                       for r in member_reqs]
-            insts = [instantiate_patterns(tp, om) for om in omegas]
+            with phase(self.counters, "instantiate"):
+                insts = [instantiate_patterns(tp, om) for om in omegas]
             segments.append(FusedSegment(tp=tp, omegas=omegas,
                                          patterns=insts,
                                          count_only=count_only))

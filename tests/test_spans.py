@@ -27,6 +27,7 @@ from repro.core.kernel_selectors import (FusedSegment, KernelSelector,
 from repro.core.metrics import phase
 from repro.core.selectors import instantiate_patterns
 from repro.core.wire import dumps
+from repro.kernels import ops as kops
 from repro.serving.http import TestClient, app_from_config, request_asgi
 
 V = encode_var
@@ -34,9 +35,10 @@ V = encode_var
 pytestmark = pytest.mark.tier1
 
 # the phases that partition a flush's host time, on Counters
-FLUSH_PHASES = ("pages_ns", "range_ns", "gather_ns", "h2d_ns",
-                "dispatch_ns", "pull_ns", "order_ns")
-COUNTER_FIELDS = FLUSH_PHASES + ("h2d_bytes", "d2h_bytes")
+FLUSH_PHASES = ("pages_ns", "instantiate_ns", "range_ns", "prune_ns",
+                "gather_ns", "h2d_ns", "dispatch_ns", "pull_ns", "order_ns")
+COUNTER_FIELDS = FLUSH_PHASES + ("h2d_bytes", "d2h_bytes", "omega_requests",
+                                 "kernel_pat_used")
 BATCH_FIELDS = ("flush_ns", "queue_wait_ns", "timer_late_ns",
                 "resident_ns", "wire_ns")
 
@@ -132,6 +134,17 @@ class TestPhase:
         assert outer.flush_ns >= inner.pages_ns + inner.order_ns
         assert inner.order_ns >= 20_000_000
 
+    @pytest.mark.parametrize("outer,inner", [("range", "prune"),
+                                             ("pages", "instantiate")])
+    def test_omega_phases_are_self_time(self, outer, inner):
+        stats = Counters()
+        with phase(stats, outer):
+            with phase(stats, inner):
+                time.sleep(0.02)
+        assert getattr(stats, inner + "_ns") >= 20_000_000
+        assert getattr(stats, outer + "_ns") < getattr(stats,
+                                                       inner + "_ns")
+
     def test_charges_when_the_block_raises(self):
         stats = Counters()
         with pytest.raises(KeyError):
@@ -206,6 +219,97 @@ def test_h2d_and_d2h_bytes_of_a_grouped_launch():
     for name in ("range_ns", "gather_ns", "h2d_ns", "dispatch_ns",
                  "pull_ns", "order_ns"):
         assert getattr(sel.counters, name) > 0, name
+
+
+def _omegas(seed=3, sizes=(3, 5)):
+    rng = np.random.default_rng(seed)
+    return [rng.choice(15, size=(m, 1), replace=False).astype(np.int32)
+            for m in sizes]
+
+
+def test_pruning_is_charged_to_prune_not_range(monkeypatch):
+    store = make_store(1)
+    sel = KernelSelector(store)
+    subranges = store.subranges
+
+    def slow(*args, **kw):
+        time.sleep(0.1)
+        return subranges(*args, **kw)
+
+    monkeypatch.setattr(store, "subranges", slow)
+    sel.select_same_pattern(TriplePattern(V(0), 3, V(1)), _omegas())
+    assert sel.counters.prune_ns >= 100_000_000
+    assert sel.counters.range_ns < 100_000_000
+
+
+def test_instantiation_is_charged_on_the_served_path(monkeypatch):
+    import repro.core.server as server_mod
+    calls = []
+
+    def slow(tp, omega):
+        calls.append(omega)
+        time.sleep(0.05)
+        return instantiate_patterns(tp, omega)
+
+    monkeypatch.setattr(server_mod, "instantiate_patterns", slow)
+    server = BrTPFServer(make_store(1), ServerConfig(
+        selector_backend="kernel", max_mpr=30))
+    tp = TriplePattern(V(0), 3, V(1))
+    reqs = [Request(tp, om) for om in _omegas()]
+    reqs.append(Request(TriplePattern(V(0), 4, V(1))))
+    server.handle_batch(reqs)
+    assert len(calls) == 3
+    c = server.counters
+    assert c.instantiate_ns >= 150_000_000
+    assert c.pages_ns + c.range_ns + c.order_ns < 150_000_000
+
+
+def test_pattern_slots_used_of_a_grouped_launch():
+    sel = KernelSelector(make_store(1))
+    tp = TriplePattern(V(0), 3, V(1))
+    omegas = _omegas()
+    sel.select_same_pattern(tp, omegas)
+    (rec,) = sel.launches
+    insts = [instantiate_patterns(tp, om) for om in omegas]
+    assert rec.pat_used == sum(len(p) for p in insts) == 8
+    assert rec.pat_slots == len(omegas) * kops.padded_pattern_slots(
+        _pow2_at_least(5))
+    # the roofline's least bytes never exceed what the launch is handed
+    assert 12 * (rec.cand_rows + rec.pat_used) <= sel.counters.h2d_bytes
+
+
+def test_pattern_slots_used_of_a_fused_launch():
+    sel = KernelSelector(make_store(2))
+    om = _omegas(sizes=(4,))[0]
+    segs = [FusedSegment(tp=TriplePattern(V(0), 3, V(1)), omegas=[om]),
+            FusedSegment(tp=TriplePattern(V(0), 4, V(1)), omegas=[None])]
+    sel.select_fused(segs)
+    (rec,) = sel.launches
+    assert rec.segments == 2
+    # segment 0 streams its pruned sub-ranges, segment 1 its whole range
+    rows = [sel.store.subranges(segs[0].tp, om).rows,
+            len(sel.store.candidate_range(segs[1].tp))]
+    assert rec.pruned and rec.cand_rows == sum(rows)
+    # each row meets its own segment's grid: 4 filled slots or 1
+    assert rec.pat_used == round((4 * rows[0] + 1 * rows[1]) / sum(rows))
+    assert 0 < rec.pat_used <= rec.pat_slots
+
+
+def test_omega_requests_count_what_the_wire_carries():
+    app = app_from_config(make_store(), ServerConfig(
+        selector_backend="kernel", max_mpr=30), batch_window_s=0.01)
+    tp = TriplePattern(V(0), 3, V(1))
+    reqs = ([Request(tp, om) for om in _omegas(sizes=(3, 5, 2))]
+            + [Request(tp), Request(tp, np.empty((0, 1), dtype=np.int32))])
+
+    async def main():
+        await _post_all(app, reqs)
+        await app.aclose()
+
+    asyncio.run(main())
+    c = app.backend.server.counters
+    assert c.omega_requests == 3
+    assert c.mappings_sent == 10
 
 
 def test_h2d_and_d2h_bytes_of_a_fused_launch():
@@ -359,8 +463,9 @@ def test_spans_reach_the_host_plane_nested_in_the_flush(tmp_path):
         jax.profiler.stop_trace()
     lines = _host_spans(str(tmp_path))
     names = {n for spans in lines.values() for n, _, _ in spans}
-    for name in ("flush", "pages", "range", "gather", "h2d", "dispatch",
-                 "pull", "order", "resident", "wire"):
+    for name in ("flush", "pages", "instantiate", "range", "prune",
+                 "gather", "h2d", "dispatch", "pull", "order", "resident",
+                 "wire"):
         assert "brtpf." + name in names, name
     nested = 0
     for spans in lines.values():
