@@ -165,21 +165,76 @@ def fusion_legality(segments: Sequence[FusedSegment], *,
     return None
 
 
+def _views(buf, shapes):
+    """Consecutive extents of the flat buffer ``buf``, reshaped to
+    ``shapes``: numpy views on the host, static slices in a program.
+    A launch's inputs and outputs each cross between host and device as
+    one such buffer, laid out by a function of the launch's shape."""
+    out, at = [], 0
+    for shape in shapes:
+        n = int(np.prod(shape))
+        out.append(buf[at:at + n].reshape(shape))
+        at += n
+    return out
+
+
+def _packed(shapes):
+    """A zeroed host int32 buffer laid out as ``shapes``, and its views."""
+    buf = np.zeros(sum(int(np.prod(shape)) for shape in shapes),
+                   dtype=np.int32)
+    return buf, _views(buf, shapes)
+
+
+def _grouped_inputs(tpad: int, g: int, m: int):
+    """cand by columns, pats, valid, base_vec, the count of real rows."""
+    return (3, tpad), (g, m, 3), (g, m), (8,), (1,)
+
+
+def _grouped_outputs(tpad: int, g: int):
+    """counts, cnts, rows and each kept row's first matching pattern."""
+    return (g,), (g,), (g, tpad), (g, tpad)
+
+
+def _fused_inputs(t_pad: int, s: int, g: int, m: int):
+    """cand by columns, seg_of_tile, the real rows of each tile, pats,
+    valid and base_vecs."""
+    tiles = t_pad // FUSED_BT
+    return (3, t_pad), (tiles,), (tiles,), (s, g, m, 3), (s, g, m), (s, 8)
+
+
+def _fused_outputs(t_pad: int, s: int, g: int):
+    """seg_counts, seg_cnts, rows and each kept row's first match."""
+    return (s, g), (s, g), (g, t_pad), (g, t_pad)
+
+
+def _first_of_rows(idx, rows):
+    """``first[g, i] = idx[rows[g, i], g]``: the first matching pattern
+    of each compacted row, gathered on the device so the ``(T, G)``
+    index never leaves it (-1 past a column's kept count)."""
+    first = jnp.take_along_axis(idx.T, jnp.maximum(rows, 0), axis=1)
+    return jnp.where(rows >= 0, first, -1)
+
+
+def _flat(*arrays):
+    return jnp.concatenate([a.astype(jnp.int32).ravel() for a in arrays])
+
+
 def _compact_epilogue(keep, idx_first, nmatch, base_mask, row_valid,
                       capacity: int):
     """Device epilogue over the grouped kernel outputs.
 
     Combines the per-group keep grid with the base pattern's
     repeated-variable mask and the padding-row mask, then produces the
-    fixed-shape compacted row indices + count per group and the
-    Definition-2 ``cnt`` (sum of per-row match counts over kept rows).
+    fixed-shape compacted row indices + count per group, the
+    Definition-2 ``cnt`` (sum of per-row match counts over kept rows)
+    and each compacted row's first matching pattern.
     """
     mask = keep & base_mask[:, None] & row_valid[:, None]   # (Tp, G)
     cnts = jnp.sum(jnp.where(mask, nmatch, 0), axis=0)      # (G,)
     rows, counts = jax.vmap(
         lambda m: kops.compact_mask(m, capacity),
         in_axes=1, out_axes=0)(mask)                        # (G, Tp), (G,)
-    return rows, counts, cnts
+    return rows, counts, cnts, _first_of_rows(idx_first, rows)
 
 
 def _count_epilogue(keep, nmatch, base_mask, row_valid):
@@ -211,8 +266,8 @@ def _fused_base_mask(cand, seg_of_row, base_vecs):
     return mask & (seg_of_row >= 0)
 
 
-def _fused_epilogue(keep, nmatch, base_mask, row_valid, seg_onehot,
-                    capacity: int):
+def _fused_epilogue(keep, idx_first, nmatch, base_mask, row_valid,
+                    seg_onehot, capacity: int):
     """Device epilogue over the fused kernel outputs.
 
     Like ``_compact_epilogue`` but segment-aware: compacted row indices
@@ -228,7 +283,7 @@ def _fused_epilogue(keep, nmatch, base_mask, row_valid, seg_onehot,
     rows, _counts = jax.vmap(
         lambda m: kops.compact_mask(m, capacity),
         in_axes=1, out_axes=0)(mask)                            # (G, Tp)
-    return rows, seg_counts, seg_cnts
+    return rows, seg_counts, seg_cnts, _first_of_rows(idx_first, rows)
 
 
 # One compiled program per launch: the kernel, the base-pattern mask and
@@ -236,35 +291,56 @@ def _fused_epilogue(keep, nmatch, base_mask, row_valid, seg_onehot,
 # flattening in the ``kops`` wrappers compile into the program once per
 # shape instead of dispatching as eager ops on every launch. The key is
 # the shapes alone: the candidate bucket, G, and the pattern grid's
-# power-of-two M.
+# power-of-two M (and S on the fused path). Each program takes its
+# inputs as one flat int32 buffer and returns its outputs as one, so a
+# launch makes one upload and one pull; the per-row tables (row
+# validity, the segment of each row) are derived here, not sent. The
+# candidates travel by columns: a (T, 3) slice of the flat buffer would
+# be laid out with its 3 padded to the chip's 128 lanes, 43x its size.
 
-@functools.partial(jax.jit, static_argnames=("capacity", "count_only"))
-def _grouped_launch(cand_k, pats, valid, cand_m, base_vec, row_valid,
-                    capacity: int, count_only: bool):
+@functools.partial(jax.jit, static_argnames=("capacity", "groups", "m",
+                                             "count_only"))
+def _grouped_launch(buf, capacity: int, groups: int, m: int,
+                    count_only: bool):
     """A grouped launch: ``bindjoin_grouped``, the base pattern's
-    ``tpf_match`` and the epilogue. Returns ``(rows, counts, cnts,
-    idx)``, or ``cnts`` alone for a count-only launch."""
-    keep, idx, nmatch = kops.bindjoin_grouped(cand_k, pats, valid)
-    base_mask = kops.tpf_match(cand_m, base_vec)
+    ``tpf_match`` and the epilogue, over ``_grouped_inputs`` packed in
+    ``buf``. Returns ``_grouped_outputs`` packed in one buffer, or the
+    ``cnts`` alone for a count-only launch."""
+    cand_cols, pats, valid, base_vec, n_rows = _views(
+        buf, _grouped_inputs(capacity, groups, m))
+    cand = cand_cols.T
+    row_valid = jnp.arange(capacity) < n_rows[0]
+    keep, idx, nmatch = kops.bindjoin_grouped(cand, pats, valid)
+    base_mask = kops.tpf_match(cand, base_vec)
     if count_only:
         return _count_epilogue(keep, nmatch, base_mask, row_valid)
-    rows, counts, cnts = _compact_epilogue(keep, idx, nmatch, base_mask,
-                                           row_valid, capacity)
-    return rows, counts, cnts, idx
+    rows, counts, cnts, first = _compact_epilogue(
+        keep, idx, nmatch, base_mask, row_valid, capacity)
+    return _flat(counts, cnts, rows, first)
 
 
-@functools.partial(jax.jit, static_argnames=("capacity",))
-def _fused_launch(cand_k, seg_of_tile, pats, valid, cand_m, seg_of_row,
-                  base_vecs, row_valid, seg_onehot, capacity: int):
+@functools.partial(jax.jit, static_argnames=("capacity", "segments",
+                                             "groups", "m"))
+def _fused_launch(buf, capacity: int, segments: int, groups: int, m: int):
     """A fused launch: ``bindjoin_fused``, the per-segment base mask and
-    the segment-aware epilogue. Returns ``(rows, seg_counts, seg_cnts,
-    idx)``."""
-    keep, idx, nmatch = kops.bindjoin_fused(cand_k, seg_of_tile, pats,
+    the segment-aware epilogue, over ``_fused_inputs`` packed in
+    ``buf``. Returns ``_fused_outputs`` packed in one buffer."""
+    cand_cols, seg_of_tile, tile_rows, pats, valid, base_vecs = _views(
+        buf, _fused_inputs(capacity, segments, groups, m))
+    cand = cand_cols.T
+    tiles = capacity // FUSED_BT
+    seg_of_row = jnp.repeat(seg_of_tile, FUSED_BT,
+                            total_repeat_length=capacity)
+    row_valid = (jnp.arange(FUSED_BT)[None, :]
+                 < tile_rows[:, None]).reshape(tiles * FUSED_BT)
+    seg_onehot = (seg_of_row[:, None]
+                  == jnp.arange(segments)[None, :]).astype(jnp.int32)
+    keep, idx, nmatch = kops.bindjoin_fused(cand, seg_of_tile, pats,
                                             valid, bt=FUSED_BT)
-    base_mask = _fused_base_mask(cand_m, seg_of_row, base_vecs)
-    rows, seg_counts, seg_cnts = _fused_epilogue(
-        keep, nmatch, base_mask, row_valid, seg_onehot, capacity)
-    return rows, seg_counts, seg_cnts, idx
+    base_mask = _fused_base_mask(cand, seg_of_row, base_vecs)
+    rows, seg_counts, seg_cnts, first = _fused_epilogue(
+        keep, idx, nmatch, base_mask, row_valid, seg_onehot, capacity)
+    return _flat(seg_counts, seg_cnts, rows, first)
 
 
 @dataclasses.dataclass
@@ -346,21 +422,35 @@ def marshal_pattern_grid(
     selector so the two backends cannot drift in how they encode a
     request (``g_slots``/``m_slots`` are each caller's padded grid).
     """
-    pats = np.full((g_slots, m_slots, 3), -1, dtype=np.int32)
-    valid = np.zeros((g_slots, m_slots), dtype=np.int32)
+    pats = np.empty((g_slots, m_slots, 3), dtype=np.int32)
+    valid = np.empty((g_slots, m_slots), dtype=np.int32)
+    write_pattern_grid(pats, valid, patterns)
+    return pats, valid, base_pattern_vec(tp)
+
+
+def write_pattern_grid(pats: np.ndarray, valid: np.ndarray,
+                       patterns: Sequence[List[TriplePattern]]) -> None:
+    """Fill ``pats`` [G, M, 3] (-1 wildcards) and ``valid`` [G, M] in
+    place: ``marshal_pattern_grid``'s grid, written where the caller
+    wants it (a view of a launch's input buffer)."""
+    pats.fill(-1)
+    valid.fill(0)
     for gi, insts in enumerate(patterns):
         for mi, p in enumerate(insts):
             pats[gi, mi] = [c if not is_var(c) else -1
                             for c in p.as_tuple()]
             valid[gi, mi] = 1
+
+
+def base_pattern_vec(tp: TriplePattern) -> np.ndarray:
+    """The int32 [8] ``tpf_match`` vector of the base pattern ``tp``."""
     comps = tp.as_tuple()
-    base_vec = kops.pattern_vec_from(
+    return kops.pattern_vec_from(
         tuple(-1 if is_var(c) else c for c in comps),
         eq_sp=int(is_var(comps[0]) and comps[0] == comps[1]),
         eq_so=int(is_var(comps[0]) and comps[0] == comps[2]),
         eq_po=int(is_var(comps[1]) and comps[1] == comps[2]),
     )
-    return pats, valid, base_vec
 
 
 def stream_order(kept: np.ndarray, first: np.ndarray,
@@ -386,21 +476,24 @@ def stream_order(kept: np.ndarray, first: np.ndarray,
     return kept[np.lexsort((sortkey, first))]
 
 
-def to_device(counters: Counters, *arrays: np.ndarray) -> tuple:
-    """Copy one launch's host inputs to the device (phase ``h2d``),
-    counting the bytes sent in ``counters.h2d_bytes``."""
+def to_device(counters: Counters, buf: np.ndarray) -> jax.Array:
+    """Send one launch's packed inputs to the device in one transfer
+    (phase ``h2d``), counted in ``h2d_copies`` and ``h2d_bytes``."""
     with phase(counters, "h2d"):
-        counters.h2d_bytes += sum(int(a.nbytes) for a in arrays)
-        return tuple(jnp.asarray(a) for a in arrays)
+        counters.h2d_copies += 1
+        counters.h2d_bytes += int(buf.nbytes)
+        return jax.device_put(buf)
 
 
-def to_host(counters: Counters, *arrays) -> tuple:
-    """Bring one launch's outputs back (phase ``pull``: the wait for the
-    device and the copy), counting the bytes in ``counters.d2h_bytes``."""
+def to_host(counters: Counters, out: jax.Array) -> np.ndarray:
+    """Bring one launch's packed outputs back in one transfer (phase
+    ``pull``: the wait for the device and the copy), counted in
+    ``d2h_copies`` and ``d2h_bytes``."""
     with phase(counters, "pull"):
-        out = tuple(np.asarray(a) for a in arrays)
-        counters.d2h_bytes += sum(int(a.nbytes) for a in out)
-        return out
+        host = np.asarray(out)
+        counters.d2h_copies += 1
+        counters.d2h_bytes += int(host.nbytes)
+        return host
 
 
 def consult_fragments(
@@ -754,38 +847,28 @@ class KernelSelector:
         tiles_pad = _pow2_at_least(total_tiles)
         t_pad = tiles_pad * bt
         with phase(counters, "gather"):
-            cand = np.zeros((t_pad, 3), dtype=np.int32)
-            row_valid = np.zeros((t_pad,), dtype=bool)
-            seg_of_tile = np.full((tiles_pad,), -1, dtype=np.int32)
-            pats_all = np.full((s_pad, g_pad, m_max, 3), -1,
-                               dtype=np.int32)
-            valid_all = np.zeros((s_pad, g_pad, m_max), dtype=np.int32)
-            base_vecs = np.zeros((s_pad, 8), dtype=np.int32)
+            buf, (cand_cols, seg_of_tile, tile_rows, pats_all, valid_all,
+                  base_vecs) = _packed(_fused_inputs(t_pad, s_pad, g_pad,
+                                                     m_max))
+            seg_of_tile.fill(-1)
+            cursors = []
             cursor = 0
             for wi, (si, pats_live, _om, _live, block, t, _pr, _full) \
                     in enumerate(work):
-                cand[cursor:cursor + t] = block
-                row_valid[cursor:cursor + t] = True
-                seg_of_tile[cursor // bt:cursor // bt + tiles[wi]] = wi
-                p_grid, v_grid, bv = marshal_pattern_grid(
-                    segments[si].tp, pats_live, g_pad, m_max)
-                pats_all[wi] = p_grid
-                valid_all[wi] = v_grid
-                base_vecs[wi] = bv
+                cand_cols[:, cursor:cursor + t] = block.T
+                cursors.append(cursor)
+                first_tile = cursor // bt
+                seg_of_tile[first_tile:first_tile + tiles[wi]] = wi
+                tile_rows[first_tile:first_tile + tiles[wi]] = np.minimum(
+                    bt, t - bt * np.arange(tiles[wi]))
+                write_pattern_grid(pats_all[wi], valid_all[wi], pats_live)
+                base_vecs[wi] = base_pattern_vec(segments[si].tp)
                 cursor += tiles[wi] * bt
-            seg_of_row = np.repeat(seg_of_tile, bt)
-            seg_onehot = (seg_of_row[:, None]
-                          == np.arange(s_pad)[None, :]).astype(np.int32)
 
-        # one copy of cand per consumer: the kernel and the base mask
-        (cand_k, seg_tile_d, pats_d, valid_d, cand_m, seg_row_d, bvs_d,
-         row_valid_d, onehot_d) = to_device(
-            counters, cand, seg_of_tile, pats_all, valid_all, cand,
-            seg_of_row, base_vecs, row_valid, seg_onehot)
+        buf_d = to_device(counters, buf)
         with phase(counters, "dispatch"):
-            rows, seg_counts, seg_cnts, idx = _fused_launch(
-                cand_k, seg_tile_d, pats_d, valid_d, cand_m, seg_row_d,
-                bvs_d, row_valid_d, onehot_d, capacity=t_pad)
+            launched = _fused_launch(buf_d, capacity=t_pad, segments=s_pad,
+                                     groups=g_pad, m=m_max)
 
         full_tiles = sum(-(-w[7] // bt) for w in work)
         rows_total = sum(w[5] for w in work)
@@ -799,13 +882,13 @@ class KernelSelector:
             segments=s, cand_rows=rows_total,
             full_rows=sum(w[7] for w in work)))
 
-        rows, seg_counts, seg_cnts, idx = to_host(
-            counters, rows, seg_counts, seg_cnts, idx)
+        seg_counts, seg_cnts, rows, first_all = _views(
+            to_host(counters, launched), _fused_outputs(t_pad, s_pad, g_pad))
         # Column g's compacted indices ascend, and segments own disjoint
         # ascending row extents: segment wi's run starts after every
         # earlier segment's kept count in that column.
         off = np.cumsum(seg_counts, axis=0) - seg_counts     # (S, G)
-        for wi, (si, pats_live, omegas_live, live, _b, _t, _pr, _full) \
+        for wi, (si, pats_live, omegas_live, live, block, _t, _pr, _full) \
                 in enumerate(work):
             seg = segments[si]
             fresh: List[Tuple[np.ndarray, int]] = []
@@ -816,9 +899,9 @@ class KernelSelector:
                     if seg.count_only or n == 0:
                         fresh.append((_EMPTY, cnt))
                         continue
-                    kept_rows = rows[gi, off[wi, gi]:off[wi, gi] + n]
-                    kept = cand[kept_rows]             # tp-index order
-                    first = idx[kept_rows, gi]
+                    run = slice(off[wi, gi], off[wi, gi] + n)
+                    kept = block[rows[gi, run] - cursors[wi]]  # tp order
+                    first = first_all[gi, run]
                     fresh.append((stream_order(kept, first,
                                                pats_live[gi]), cnt))
             self._finish_segment(seg, omegas_live, fresh, results[si],
@@ -901,24 +984,20 @@ class KernelSelector:
         m = _pow2_at_least(max(len(p) for p in patterns))
         counters = self.counters
         with phase(counters, "gather"):
-            pats, valid, base_vec = marshal_pattern_grid(tp, patterns,
-                                                         g, m)
             # Pad the candidate block to a shape bucket (bounded jit
             # cache).
             tpad = _bucket(t)
-            cand = np.zeros((tpad, 3), dtype=np.int32)
-            cand[:t] = block
-            row_valid = np.zeros((tpad,), dtype=bool)
-            row_valid[:t] = True
+            buf, (cand_cols, pats, valid, base_vec, n_rows) = _packed(
+                _grouped_inputs(tpad, g, m))
+            cand_cols[:, :t] = block.T
+            write_pattern_grid(pats, valid, patterns)
+            base_vec[:] = base_pattern_vec(tp)
+            n_rows[0] = t
 
-        # one copy of cand per consumer: the kernel and the base mask
-        cand_k, pats_d, valid_d, cand_m, base_vec_d, row_valid_d = \
-            to_device(counters, cand, pats, valid, cand, base_vec,
-                      row_valid)
+        buf_d = to_device(counters, buf)
         with phase(counters, "dispatch"):
-            launched = _grouped_launch(
-                cand_k, pats_d, valid_d, cand_m, base_vec_d, row_valid_d,
-                capacity=tpad, count_only=count_only)
+            launched = _grouped_launch(buf_d, capacity=tpad, groups=g, m=m,
+                                       count_only=count_only)
 
         mp = kops.padded_pattern_slots(m)
         self.launches.append(
@@ -928,10 +1007,10 @@ class KernelSelector:
                          cand_rows=t, full_rows=full))
 
         if count_only:
-            (cnts,) = to_host(counters, launched)
-            return [(_EMPTY, int(c)) for c in cnts]
+            return [(_EMPTY, int(c)) for c in to_host(counters, launched)]
 
-        rows, counts, cnts, idx = to_host(counters, *launched)
+        counts, cnts, rows, first_all = _views(
+            to_host(counters, launched), _grouped_outputs(tpad, g))
         out: List[Tuple[np.ndarray, int]] = []
         with phase(counters, "order"):
             for gi in range(g):
@@ -939,9 +1018,8 @@ class KernelSelector:
                 if n == 0:
                     out.append((_EMPTY, int(cnts[gi])))
                     continue
-                kept_rows = rows[gi, :n]
-                kept = cand[kept_rows]                 # tp-index order
-                first = idx[kept_rows, gi]             # first matching pattern
+                kept = block[rows[gi, :n]]             # tp-index order
+                first = first_all[gi, :n]              # first matching pattern
                 out.append((self._stream_order(kept, first,
                                                patterns[gi]),
                             int(cnts[gi])))

@@ -79,9 +79,11 @@ class Counters:
     gather_ns: int = 0              # candidate block, padding, grids
     h2d_ns: int = 0                 # host->device copies of launch inputs
     h2d_bytes: int = 0              # ... bytes sent
+    h2d_copies: int = 0             # ... transfers made (one a launch)
     dispatch_ns: int = 0            # kernel/epilogue calls (compiles too)
     pull_ns: int = 0                # device wait + device->host copies
     d2h_bytes: int = 0              # ... bytes brought back
+    d2h_copies: int = 0             # ... transfers made (one a launch)
     order_ns: int = 0               # kept-row gather + stream_order
 
     def merge(self, other: "Counters") -> None:

@@ -349,3 +349,76 @@ class TestLaunchProgram:
                 assert data.dtype == w_data.dtype
                 np.testing.assert_array_equal(data, w_data)
                 assert cnt == w_cnt
+
+
+def edge_store():
+    """Ranges of known sizes: 1,024 rows of predicate 3 (one whole
+    candidate bucket), 300 of 5 (two fused tiles, the last partly
+    filled), 512 of 6 and 256 of 7 (whole tiles)."""
+    parts = [np.stack([np.arange(n), np.full(n, p), 2000 + np.arange(n) % 7],
+                      axis=1)
+             for p, n in ((3, 1024), (5, 300), (6, 512), (7, 256))]
+    return TripleStore(np.concatenate(parts).astype(np.int32))
+
+
+def assert_matches_block_numpy(store, tp, omegas, got):
+    patterns = [instantiate_patterns(tp, om) for om in omegas]
+    want = select_block_numpy(store.candidate_range(tp).triples, tp,
+                              patterns)
+    for (data, cnt), (w_data, w_cnt) in zip(got, want, strict=True):
+        assert data.dtype == w_data.dtype
+        np.testing.assert_array_equal(data, w_data)
+        assert cnt == w_cnt
+
+
+class TestPackedLayout:
+    """Each launch's inputs and outputs cross as one buffer each way;
+    the edges of that layout, against the numpy evaluation."""
+
+    store = edge_store()
+
+    def test_group_with_no_kept_row(self):
+        # the full wildcard: the bucket's padding rows would match it
+        # but for the row count sent with the candidates
+        tp = TriplePattern(V(0), V(1), V(2))
+        absent = np.array([[5000, UNBOUND, UNBOUND]], np.int32)
+        omegas = [bound_omega(4), absent, None]
+        sel = KernelSelector(self.store)
+        got = sel.select_same_pattern(tp, omegas)
+        (rec,) = sel.launches
+        assert rec.groups == 3 and not rec.fast_path
+        assert rec.cand_rows == 2092 < rec.cand_streamed
+        assert got[1] == (got[1][0], 0) and got[1][0].shape == (0, 3)
+        assert_matches_block_numpy(self.store, tp, omegas, got)
+
+    def test_group_whose_kept_count_equals_the_capacity(self):
+        tp = TriplePattern(V(0), 3, V(1))
+        omegas = [None, bound_omega(3)]
+        sel = KernelSelector(self.store)
+        got = sel.select_same_pattern(tp, omegas)
+        (rec,) = sel.launches
+        assert len(got[0][0]) == rec.cand_streamed == rec.cand_rows == 1024
+        assert_matches_block_numpy(self.store, tp, omegas, got)
+
+    @pytest.mark.parametrize("preds,rows", [
+        # 300 rows in 2 tiles, 4 tiles in all
+        pytest.param((5, 6), (300, 512), id="partly_filled_last_tile"),
+        # the full wildcard, which padding rows would match: 2,092 rows
+        # in 9 tiles, the last partly filled
+        pytest.param((V(1), 6), (2092, 512), id="wildcard_last_tile"),
+        # 3 whole tiles padded to 4: one tile of no segment
+        pytest.param((7, 6), (256, 512), id="dead_tile"),
+    ])
+    def test_fused_tiles(self, preds, rows):
+        segs = [FusedSegment(TriplePattern(V(0), p, V(2)),
+                             [None, bound_omega(2, 100 * i)])
+                for i, p in enumerate(preds)]
+        sel = KernelSelector(self.store)
+        got = sel.select_fused(segs)
+        (rec,) = sel.launches
+        assert rec.segments == 2 and rec.cand_rows == sum(rows)
+        tiles = sum(-(-r // ks.FUSED_BT) for r in rows)
+        assert rec.cand_streamed == ks._pow2_at_least(tiles) * ks.FUSED_BT
+        assert (rows[0] % ks.FUSED_BT != 0) or tiles & (tiles - 1)
+        for seg, res in zip(segs, got, strict=True):
+            assert_matches_block_numpy(self.store, seg.tp, seg.omegas, res)

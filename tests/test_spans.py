@@ -37,7 +37,8 @@ pytestmark = pytest.mark.tier1
 # the phases that partition a flush's host time, on Counters
 FLUSH_PHASES = ("pages_ns", "instantiate_ns", "range_ns", "prune_ns",
                 "gather_ns", "h2d_ns", "dispatch_ns", "pull_ns", "order_ns")
-COUNTER_FIELDS = FLUSH_PHASES + ("h2d_bytes", "d2h_bytes", "omega_requests",
+COUNTER_FIELDS = FLUSH_PHASES + ("h2d_bytes", "d2h_bytes", "h2d_copies",
+                                 "d2h_copies", "omega_requests",
                                  "kernel_pat_used")
 BATCH_FIELDS = ("flush_ns", "queue_wait_ns", "timer_late_ns",
                 "resident_ns", "wire_ns")
@@ -207,14 +208,14 @@ def test_h2d_and_d2h_bytes_of_a_grouped_launch():
     tpad, g = rec.cand_streamed, rec.groups
     m = _pow2_at_least(max(len(instantiate_patterns(tp, om))
                            for om in omegas))  # the grid's padded M
-    sent = (2 * tpad * 3 * 4               # cand, to the kernel and mask
+    sent = (tpad * 3 * 4                   # cand, once
             + g * m * 3 * 4 + g * m * 4    # pattern grid and its validity
             + 8 * 4                        # base-pattern vector
-            + tpad)                        # row validity, one byte a row
+            + 4)                           # the count of real rows
     assert sel.counters.h2d_bytes == sent
-    back = (g * tpad * 4                   # compacted rows
-            + g * 4 + g * 4                # kept counts, cnt
-            + tpad * g * 4)                # first-match index
+    back = (g * 4 + g * 4                  # kept counts, cnt
+            + g * tpad * 4                 # compacted rows
+            + g * tpad * 4)                # their first matching patterns
     assert sel.counters.d2h_bytes == back
     for name in ("range_ns", "gather_ns", "h2d_ns", "dispatch_ns",
                  "pull_ns", "order_ns"):
@@ -322,18 +323,36 @@ def test_h2d_and_d2h_bytes_of_a_fused_launch():
     assert rec.segments == 3
     t_pad = rec.cand_streamed
     s_pad, g_pad, m_max = _pow2_at_least(3), 1, 1
-    sent = (2 * t_pad * 3 * 4              # cand, to the kernel and mask
-            + t_pad // 256 * 4             # segment of each tile
+    sent = (t_pad * 3 * 4                  # cand, once
+            + 2 * t_pad // 256 * 4         # segment and real rows of a tile
             + s_pad * g_pad * m_max * 16   # pattern grid and validity
-            + t_pad * 4                    # segment of each row
-            + s_pad * 8 * 4                # base-pattern vectors
-            + t_pad                        # row validity
-            + t_pad * s_pad * 4)           # one-hot segment table
+            + s_pad * 8 * 4)               # base-pattern vectors
     assert sel.counters.h2d_bytes == sent
-    back = (g_pad * t_pad * 4              # compacted rows
-            + 2 * s_pad * g_pad * 4        # kept counts, cnt per segment
-            + t_pad * g_pad * 4)           # first-match index
+    back = (2 * s_pad * g_pad * 4          # kept counts, cnt per segment
+            + g_pad * t_pad * 4            # compacted rows
+            + g_pad * t_pad * 4)           # their first matching patterns
     assert sel.counters.d2h_bytes == back
+
+
+@pytest.mark.parametrize("kind", ["grouped", "count_only", "fused"])
+def test_one_upload_and_one_pull_per_launch(kind):
+    sel = KernelSelector(make_store(2))
+    tp = TriplePattern(V(0), 3, V(1))
+    om = _omegas(sizes=(4,))[0]
+    for launches in (1, 2):
+        if kind == "grouped":
+            sel.select_same_pattern(tp, [om, None])
+        elif kind == "count_only":
+            sel.select_count(tp, om)
+        else:
+            sel.select_fused([
+                FusedSegment(tp=tp, omegas=[om]),
+                FusedSegment(tp=TriplePattern(V(0), 4, V(1)),
+                             omegas=[None])])
+        assert len(sel.launches) == launches
+        assert sel.launches[-1].segments == (2 if kind == "fused" else 1)
+        assert sel.counters.h2d_copies == launches
+        assert sel.counters.d2h_copies == launches
 
 
 def test_queue_wait_covers_the_window_on_a_timer_flush():

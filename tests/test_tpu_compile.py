@@ -8,6 +8,7 @@ import time, so every test worker collects the same tests and only the
 worker running this file loads the TPU library.
 """
 import importlib
+import math
 import os
 
 import jax
@@ -16,8 +17,10 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core.federation import _MIN_TILE
-from repro.core.kernel_selectors import (FUSED_BT, _fused_launch,
-                                         _grouped_launch)
+from repro.core.kernel_selectors import (FUSED_BT, _fused_inputs,
+                                         _fused_launch, _fused_outputs,
+                                         _grouped_inputs, _grouped_launch,
+                                         _grouped_outputs)
 
 # The package re-exports ``bindjoin``/``tpf_match`` as functions, which
 # shadow the submodules of the same names.
@@ -49,13 +52,15 @@ def one_chip():
     jax.config.update("jax_enable_compilation_cache", enabled)
 
 
-def _compile(fn, sharding, *shapes, dtypes=None):
-    """Lower ``fn`` for int32 arguments of ``shapes`` (``dtypes`` names
-    any other, by position) and compile it for the described chip."""
-    dtypes = dtypes or {}
-    args = [jax.ShapeDtypeStruct(s, dtypes.get(i, jnp.int32),
-                                 sharding=sharding)
-            for i, s in enumerate(shapes)]
+def _size(shapes):
+    return sum(math.prod(s) for s in shapes)
+
+
+def _compile(fn, sharding, *shapes):
+    """Lower ``fn`` for int32 arguments of ``shapes`` and compile it for
+    the described chip."""
+    args = [jax.ShapeDtypeStruct(s, jnp.int32, sharding=sharding)
+            for s in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
     mem = compiled.memory_analysis()
@@ -119,17 +124,23 @@ def launch_chip(one_chip, monkeypatch):
                          ids=["rows", "count"])
 def test_grouped_launch_program_compiles(launch_chip, tpad, count_only):
     g, m = LAUNCH_G, LAUNCH_M
-    _compile(lambda *a: _grouped_launch(*a, capacity=tpad,
-                                        count_only=count_only),
-             launch_chip,
-             (tpad, 3), (g, m, 3), (g, m), (tpad, 3), (8,), (tpad,),
-             dtypes={5: jnp.bool_})
+    n = _size(_grouped_inputs(tpad, g, m))
+    compiled = _compile(
+        lambda buf: _grouped_launch(buf, capacity=tpad, groups=g, m=m,
+                                    count_only=count_only),
+        launch_chip, (n,))
+    # one buffer back: the counts, or counts, cnts, rows and first
+    out = compiled.out_info
+    assert out.shape == ((g,) if count_only
+                         else (_size(_grouped_outputs(tpad, g)),))
 
 
 @pytest.mark.parametrize("tpad", [8192, 131072])
 def test_fused_launch_program_compiles(launch_chip, tpad):
     s, g, m = LAUNCH_S, LAUNCH_G, LAUNCH_M
-    _compile(lambda *a: _fused_launch(*a, capacity=tpad), launch_chip,
-             (tpad, 3), (tpad // FUSED_BT,), (s, g, m, 3), (s, g, m),
-             (tpad, 3), (tpad,), (s, 8), (tpad,), (tpad, s),
-             dtypes={7: jnp.bool_})
+    n = _size(_fused_inputs(tpad, s, g, m))
+    compiled = _compile(
+        lambda buf: _fused_launch(buf, capacity=tpad, segments=s, groups=g,
+                                  m=m),
+        launch_chip, (n,))
+    assert compiled.out_info.shape == (_size(_fused_outputs(tpad, s, g)),)
